@@ -346,6 +346,20 @@ def main():
 
     import jax
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+    if mesh_dims and jax.device_count() < int(np.prod(mesh_dims)):
+        # e.g. a non-CPU default backend: the forced-host-device flag only
+        # adds CPU-platform devices. This bench is the sharded-correctness
+        # gate, so a mesh it cannot build is a failure, not a skip.
+        print(f"[serve_bench] FAIL: mesh {ARGS.mesh} needs "
+              f"{int(np.prod(mesh_dims))} devices, have "
+              f"{jax.device_count()} ({jax.default_backend()}); pass "
+              f"--mesh '' to run without the sharded comparison",
+              file=sys.stderr)
+        sys.exit(1)
+
     from repro.configs import get_smoke_config, with_swat
     from repro.core import model as Mod
     from repro.launch.mesh import parse_mesh
@@ -469,15 +483,7 @@ def main():
         },
     }
     shard_same = True
-    if mesh_dims and jax.device_count() < int(np.prod(mesh_dims)):
-        # e.g. a non-CPU default backend: the forced-host-device flag only
-        # adds CPU-platform devices. Never skip silently — this bench is
-        # advertised as the sharded-correctness gate.
-        print(f"[serve_bench] WARN: sharded comparison SKIPPED — mesh "
-              f"{ARGS.mesh} needs {int(np.prod(mesh_dims))} devices, "
-              f"have {jax.device_count()} ({jax.default_backend()})",
-              file=sys.stderr)
-    elif mesh_dims:
+    if mesh_dims:
         mesh = parse_mesh(ARGS.mesh)
         shard, shard_tps, shard_eng = run_mode(
             cfg, params, reqs, scan_steps=ARGS.scan_steps,

@@ -25,11 +25,12 @@ import numpy as np                                            # noqa: E402
 from repro.core.types import AttentionSpec                    # noqa: E402
 from repro.distributed import context_parallel as CP          # noqa: E402
 from repro.kernels import ref as R                            # noqa: E402
+from repro.launch.mesh import make_mesh                       # noqa: E402
 
 
 def main():
     assert len(jax.devices()) == 4
-    mesh = jax.make_mesh((4,), ("seq",))
+    mesh = make_mesh((4,), ("seq",))
     spec = AttentionSpec(kind="swat", window=256, num_global=16, causal=True)
 
     B, H, L, D = 1, 4, 4096, 64
@@ -38,7 +39,7 @@ def main():
     k = jnp.asarray(rng.randn(B, H, L, D), jnp.float32) * 0.3
     v = jnp.asarray(rng.randn(B, H, L, D), jnp.float32) * 0.3
 
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         out = CP.swat_attention_context_parallel(
             q, k, v, spec, mesh=mesh, axis="seq")
     ref = R.attention_ref(q, k, v, spec)

@@ -45,7 +45,7 @@ def main():
                                    jnp.int32)}
     batch["labels"] = batch["tokens"]
 
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         loss_fn = PP.make_pipeline_loss(cfg, pcfg, mesh)
         l_pp, _ = jax.jit(loss_fn)(params, batch)
     l_ref, _ = Mod.loss_fn(params, cfg, batch, remat=False)
@@ -54,7 +54,7 @@ def main():
 
     opt_cfg = adamw.AdamWConfig(lr=3e-4, warmup_steps=5)
     opt = adamw.init_opt_state(params)
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         step = jax.jit(PP.make_pp_train_step(cfg, opt_cfg, pcfg, mesh))
         for i in range(20):
             t0 = time.time()

@@ -307,7 +307,7 @@ def paged_scatter(pool, table, view):
 
 
 def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
-                     impl: str = "ref", lookahead: int = 0):
+                     impl: str = "ref", lookahead: int = 0, mesh=None):
     """T-token decode. x: (B, T, Dm). Ring insertion at (step mod cap) for
     sparse specs — the paper's FIFO replacement policy (row index mod window)
     — happens INSIDE the attention call (`decode_attention(new_kv=)`): the
@@ -320,7 +320,8 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
     ropes, and masks at its own depth, which is what lets one batched call
     serve slots mid-flight at different positions. T > 1 (the speculative-
     decode verify primitive) needs a cache allocated with lookahead >= T-1
-    so the step's own inserts never evict an in-window token."""
+    so the step's own inserts never evict an in-window token. mesh: the
+    mesh the caller's jit is partitioned over (see `decode_attention`)."""
     b, t, _ = x.shape
     q, k_new, v_new = _project_qkv(params, cfg, x, x)
     step = jnp.broadcast_to(jnp.asarray(cache["step"], jnp.int32), (b,))
@@ -347,7 +348,7 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
             "tokens: allocate caches with lookahead >= T-1")
     out, k_cache, v_cache = kops.decode_attention(
         q, k_arr, v_arr, None, cfg.spec, impl=impl,
-        new_kv=(k_new, v_new), pos=step, ring_cap=cap)
+        new_kv=(k_new, v_new), pos=step, ring_cap=cap, mesh=mesh)
     out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
     if paged:
         new_cache = {**cache,

@@ -361,7 +361,7 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int,
 
 def _apply_layer_decode(p, cfg, kind, x, cache, *, enc_out=None,
                         impl: str = "ref", lookahead: int = 0,
-                        index: Optional[int] = None):
+                        index: Optional[int] = None, mesh=None):
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     if kind.startswith("mamba"):
         y, new_cache = S.mamba_decode(p["mixer"], h, cache, cfg.ssm)
@@ -369,7 +369,7 @@ def _apply_layer_decode(p, cfg, kind, x, cache, *, enc_out=None,
         y, new_cache = L.attention_decode(p["mixer"],
                                           attn_cfg(cfg, kind, index=index),
                                           h, cache, impl=impl,
-                                          lookahead=lookahead)
+                                          lookahead=lookahead, mesh=mesh)
     x = x + y
     if kind == "xattn":
         h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
@@ -394,7 +394,7 @@ def _apply_layer_decode(p, cfg, kind, x, cache, *, enc_out=None,
 
 def decode_step(params: Params, cfg: ModelConfig, batch, caches, *,
                 impl: str = "xla", unroll: bool = False,
-                act_sharding=None, lookahead: int = 0):
+                act_sharding=None, lookahead: int = 0, mesh=None):
     """T tokens for every sequence (usually T=1). batch:
     {"tokens": (B, T)} (or {"embeddings": (B, T, D)}). Per-slot cache
     steps: rows may sit at different positions (continuous batching).
@@ -407,7 +407,9 @@ def decode_step(params: Params, cfg: ModelConfig, batch, caches, *,
     jnp reference. act_sharding: optional (B, T, D) sharding pinned at
     every super-block boundary — under a serving mesh this keeps the decode
     residual stream slot-sharded instead of letting SPMD replicate it
-    between blocks. Returns (logits (B, T, V), new caches)."""
+    between blocks. mesh: the serving mesh the caller's jit is partitioned
+    over — the pallas kernel then runs per shard (`ops.decode_attention`).
+    Returns (logits (B, T, V), new caches)."""
     x = embed_tokens(params, cfg, batch)
     assert x.shape[1] == 1 or all(
         not k.startswith("mamba") for k in cfg.layer_pattern), \
@@ -420,7 +422,8 @@ def decode_step(params: Params, cfg: ModelConfig, batch, caches, *,
         for i, kind in enumerate(cfg.layer_pattern):
             x, nc = _apply_layer_decode(blk_p[f"l{i}"], cfg, kind, x,
                                         blk_cache[f"l{i}"], impl=dec_impl,
-                                        lookahead=lookahead, index=i)
+                                        lookahead=lookahead, index=i,
+                                        mesh=mesh)
             new_caches[f"l{i}"] = nc
         return L.with_activation_constraint(x, act_sharding), new_caches
 

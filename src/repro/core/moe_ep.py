@@ -56,8 +56,7 @@ def moe_ffn_ep_local(params: Params, x, spec: MoESpec, *, axis: str,
 
     x: (B, L_local, D); params['w1'/'w3'/'w2'] carry the LOCAL experts on
     dim 0 (E/n each); params['router'] is replicated (D, E_global)."""
-    from repro.core.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     b, l, d = x.shape
     e = spec.num_experts
     e_local = params["w1"].shape[0]
@@ -146,10 +145,9 @@ def moe_ffn_ep(params: Params, x, spec: MoESpec, *, mesh: Mesh,
     in_specs = ({"router": P(), "w1": P(axis), "w3": P(axis),
                  "w2": P(axis)},
                 P(None, axis, None))
-    from repro.core.compat import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P(None, axis, None), P()),
-                   axis_names={axis}, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(None, axis, None), P()),
+                       axis_names={axis}, check_vma=False)
     return fn({k: params[k] for k in ("router", "w1", "w3", "w2")}, x)
 
 

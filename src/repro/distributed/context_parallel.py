@@ -72,8 +72,7 @@ def _shift_in(x, axis: str, hops: int, direction: int, rows: int):
     direction=-1: right halo. Non-receiving edge devices get zeros (masked
     by kv bounds downstream). Returns the concatenation in sequence order.
     """
-    from repro.core.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     lp = x.shape[2]
     if hops == 0 or n == 1:
         return x[:, :, :0]
@@ -156,8 +155,7 @@ def swat_attention_cp_local(q, k, v, idx_arr=None, *, spec: AttentionSpec,
     b, hq, lp, d = q.shape
     hkv = k.shape[1]
     scale = float(d ** -0.5 if scale is None else scale)
-    from repro.core.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis) if idx_arr is None else idx_arr[0]
     assert lp * n == seq_len, (lp, n, seq_len)
     w, g = spec.window, spec.num_global
@@ -252,11 +250,10 @@ def swat_attention_context_parallel(
     body = functools.partial(
         swat_attention_cp_local, spec=spec, axis=axis, seq_len=lq,
         block_q=block_q, block_kv=block_kv, scale=scale)
-    from repro.core.compat import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(io_spec,) * 3 + (P(axis),),
-                   out_specs=io_spec, axis_names={axis},
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(io_spec,) * 3 + (P(axis),),
+                       out_specs=io_spec, axis_names={axis},
+                       check_vma=False)
     # shard index travels as data (see swat_attention_cp_local docstring)
     return fn(q, k, v, jnp.arange(n, dtype=jnp.int32))
 

@@ -74,8 +74,7 @@ def pipeline_loss_fn(params, cfg: ModelConfig, batch, pcfg: PipelineConfig,
     the stage id travels AS DATA (pipe-sharded iota) because
     lax.axis_index under partial-manual shard_map lowers to a PartitionId
     op the SPMD partitioner rejects (same workaround as context_parallel)."""
-    from repro.core.compat import axis_size
-    n = axis_size(pcfg.axis)
+    n = jax.lax.axis_size(pcfg.axis)
     stage = (jax.lax.axis_index(pcfg.axis) if stage_idx is None
              else stage_idx[0])
     m = pcfg.num_microbatches
@@ -162,18 +161,12 @@ def make_pipeline_loss(cfg: ModelConfig, pcfg: PipelineConfig, mesh: Mesh,
             jax.tree.map(lambda _: P(), batch),
             P(pcfg.axis),
         )
-        from repro.core.compat import shard_map
-        # partial-manual (pipe manual, data/model auto) is the intent; the
-        # legacy XLA SPMD partitioner rejects partial-manual programs
-        # (IsManualSubgroup check), so on old jax run fully manual — the
-        # other axes just replicate this loss, which only uses `pipe`.
-        manual = ({pcfg.axis} if hasattr(jax, "shard_map")
-                  else set(mesh.axis_names))
-        fn = shard_map(
+        # partial-manual: pipe is manual, data/model stay auto
+        fn = jax.shard_map(
             lambda p, b, s: body(p, batch=b, stage_idx=s),
             mesh=mesh, in_specs=in_specs,
             out_specs=(P(), {"loss": P(), "aux_loss": P(), "tokens": P()}),
-            axis_names=manual, check_vma=False)
+            axis_names={pcfg.axis}, check_vma=False)
         # stage index as pipe-sharded data (see pipeline_loss_fn docstring)
         return fn(params, batch,
                   jnp.arange(pcfg.num_stages, dtype=jnp.int32))

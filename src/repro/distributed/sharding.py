@@ -258,5 +258,14 @@ def decode_batch_sharding(shapes, mesh: Mesh, slots: int,
     return jax.tree_util.tree_map(leaf, shapes)
 
 
+def decode_kernel_spec(shape, mesh: Mesh) -> P:
+    """Spec of a (B, H, W, D) cache inside the decode kernel's shard_map:
+    the cache rule's slot and head placements, never the ring axis — the
+    kernel's softmax runs over the whole ring, so a W shard would need a
+    cross-shard merge. Where the cache rule split W (kv heads fewer than
+    the model axis), XLA gathers it at the shard_map boundary."""
+    return _spec_for(tuple(shape), _CACHE_RULES[0][1][:2], mesh)
+
+
 def replicated(mesh: Mesh):
     return NamedSharding(mesh, P())

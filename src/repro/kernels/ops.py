@@ -302,11 +302,28 @@ def _per_slot(x, b: int):
                             (b,))
 
 
+def _shard_decode_kernel(call, mesh, cache_shape, fuse: bool):
+    """XLA does not partition a Mosaic kernel: under a mesh the decode
+    kernel runs per shard inside shard_map. Slots and KV heads are
+    independent in the kernel, so it shards over them as the serving cache
+    sharding places them (`sharding.decode_kernel_spec`); q and the output
+    follow the cache (a query-head block maps onto its KV-head block)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import decode_kernel_spec
+    kv = decode_kernel_spec(cache_shape, mesh)     # all four dims named
+    vec = P(kv[0])
+    in_specs = (kv, kv, kv, vec) + ((kv, kv, vec) if fuse else ())
+    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                         out_specs=(kv, kv, kv) if fuse else kv,
+                         check_vma=False)
+
+
 def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
                      scale: Optional[float] = None, impl: str = "ref",
                      interpret: Optional[bool] = None,
                      new_kv=None, num_new=None, pos=None,
-                     ring_cap: Optional[int] = None):
+                     ring_cap: Optional[int] = None, mesh=None):
     """Decode T >= 1 tokens vs a (ring) KV cache. q: (B, Hq, T, D).
     cache_len / pos / num_new are per-slot (scalar, (B,) or (B,1,1,1)): a
     continuously-batched engine serves slots at different ring depths from
@@ -333,7 +350,8 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
     impl="ref" is the jnp path (CPU tests, dry-run lowering); "pallas" is
     the swat_decode flash kernel (the TPU hot path; interpret mode
     elsewhere). Ring order is irrelevant either way — softmax is
-    permutation invariant."""
+    permutation invariant. mesh: the mesh the caller's jit is partitioned
+    over; the pallas kernel then runs per shard (`_shard_decode_kernel`)."""
     b, _, t, _ = q.shape
     w_phys = k_cache.shape[2]
     cap = w_phys if ring_cap is None else int(ring_cap)
@@ -374,13 +392,19 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
     if impl == "pallas":
         from repro.kernels.swat_decode import swat_decode
         interpret = default_interpret() if interpret is None else interpret
-        k_new, v_new = new_kv if fuse else (None, None)
-        return swat_decode(q, k_cache, v_cache, pos,
-                           new_k=k_new, new_v=v_new,
-                           num_new=nn if fuse else None,
-                           ring_cap=cap, num_global=g, window=window,
-                           causal=spec.causal, scale=scale,
-                           softcap=spec.softcap, interpret=interpret)
+        kern = functools.partial(
+            swat_decode, ring_cap=cap, num_global=g, window=window,
+            causal=spec.causal, scale=scale, softcap=spec.softcap,
+            interpret=interpret)
+        if fuse:
+            def call(q, kc, vc, pos, kn, vn, nn):
+                return kern(q, kc, vc, pos, new_k=kn, new_v=vn, num_new=nn)
+            args = (q, k_cache, v_cache, pos) + tuple(new_kv) + (nn,)
+        else:
+            call, args = kern, (q, k_cache, v_cache, pos)
+        if mesh is not None and mesh.size > 1:
+            call = _shard_decode_kernel(call, mesh, k_cache.shape, fuse)
+        return call(*args)
     if fuse:
         k_new, v_new = new_kv
         k_cache = ref_impl.ring_insert_ref(k_cache, k_new, pos, nn,

@@ -169,8 +169,11 @@ def _decode_kernel(pos_ref, nn_ref, q_ref, k_ref, v_ref, *rest,
     last = total - 1
     t_ring = last - _pmod((last - g) - (s_idx - g), ring)
     if g > 0:
-        t_s = jnp.where(s_idx < g, s_idx, t_ring)
-        valid = jnp.where(s_idx < g, s_idx < total, t_ring >= g)
+        pinned = s_idx < g
+        t_s = jnp.where(pinned, s_idx, t_ring)
+        # masks combine with &/|, never a select over booleans: Mosaic
+        # cannot lower a select whose values are i1 vectors
+        valid = (pinned & (s_idx < total)) | (~pinned & (t_ring >= g))
     else:
         t_s = t_ring
         valid = t_ring >= 0

@@ -119,6 +119,10 @@ def main():
 
     import jax
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.configs import get_config, get_smoke_config, with_swat
     from repro.core import model as Mod
     from repro.launch.mesh import parse_mesh
@@ -131,7 +135,10 @@ def main():
     if args.swat:
         cfg = with_swat(cfg, window=args.window, num_global=4)
     mesh = parse_mesh(args.mesh) if args.mesh else None
-    params = Mod.init_model(jax.random.PRNGKey(0), cfg)
+    # jitted: full-size configs draw their weights on the device in one
+    # program instead of op by op
+    params = jax.jit(Mod.init_model, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
     plan = FaultPlan(
         poison_logits=(((args.chaos_poison_slot, args.chaos_poison_step,
                          "nan"),)

@@ -41,6 +41,9 @@ def main():
 
     import jax
     import jax.numpy as jnp
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.configs import get_config, get_smoke_config
     from repro.data.pipeline import DataConfig, SyntheticLM
     from repro.distributed import sharding as Sh

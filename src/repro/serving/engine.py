@@ -539,7 +539,7 @@ class _Compiled:
                 caches, tok, active, budget, key, poisoned, fin, mx = carry
                 logits, caches = Mod.decode_step(
                     params, cfg, {"tokens": tok[:, None]}, caches, impl=impl,
-                    act_sharding=act, lookahead=lookahead)
+                    act_sharding=act, lookahead=lookahead, mesh=self.mesh)
                 lg = logits[:, 0]
                 if inject:
                     # chaos countdown: when a slot's trigger step arrives,
@@ -719,7 +719,7 @@ class _Compiled:
                 x = jnp.concatenate([tok[:, None], drafts], axis=1)
                 logits, caches = Mod.decode_step(
                     params, cfg, {"tokens": x}, caches, impl=impl,
-                    act_sharding=act, lookahead=k)
+                    act_sharding=act, lookahead=k, mesh=self.mesh)
                 if inject:
                     # a spec step verifies a window of T positions; poison
                     # exactly the position the countdown lands on (window

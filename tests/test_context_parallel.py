@@ -22,16 +22,17 @@ COMMON = """
     from repro.core.types import AttentionSpec
     from repro.distributed import context_parallel as CP
     from repro.kernels import ref as R
+    from repro.launch.mesh import make_mesh
 
     assert len(jax.devices()) == 4
-    mesh = jax.make_mesh((4,), ("seq",))
+    mesh = make_mesh((4,), ("seq",))
 
     def run_case(spec, b=2, hq=4, hkv=2, l=64, d=16, tol=2e-2):
         rng = np.random.RandomState(0)
         q = jnp.asarray(rng.randn(b, hq, l, d), jnp.float32) * 0.5
         k = jnp.asarray(rng.randn(b, hkv, l, d), jnp.float32) * 0.5
         v = jnp.asarray(rng.randn(b, hkv, l, d), jnp.float32) * 0.5
-        with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+        with jax.set_mesh(mesh):
             got = CP.swat_attention_context_parallel(
                 q, k, v, spec, mesh=mesh, axis="seq",
                 block_q=16, block_kv=16)
@@ -118,7 +119,7 @@ def test_cp_gradients():
         t = jnp.asarray(rng.randn(b, hq, l, d), jnp.float32)
 
         def loss_cp(q, k, v):
-            with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+            with jax.set_mesh(mesh):
                 o = CP.swat_attention_context_parallel(
                     q, k, v, spec, mesh=mesh, axis="seq",
                     block_q=16, block_kv=16)

@@ -8,10 +8,7 @@ sees. Outputs must match the oracle's rows. Also: the kv_lo bound masks
 """
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # bare env: deterministic seeded fallback (tier-1)
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

@@ -33,8 +33,9 @@ COMMON = """
     from repro.core import moe as M
     from repro.core import moe_ep as EP
     from repro.core.types import MoESpec
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     spec = MoESpec(num_experts=8, top_k=2)
     p = M.init_moe(jax.random.PRNGKey(0), 32, 64, spec, dtype=jnp.float32)
     x = jnp.asarray(np.random.RandomState(0).randn(2, 16, 32),
@@ -46,7 +47,7 @@ COMMON = """
 def test_ep_matches_dense_ref_no_drops():
     run_sub(COMMON + """
     ref = M.moe_ffn_dense_ref(p, x, spec)
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         out, aux = EP.moe_ffn_ep(p, x, spec, mesh=mesh, axis="model",
                                  capacity_factor=8.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -61,7 +62,7 @@ def test_ep_matches_sort_dispatch_aux():
     """aux (load-balance statistic) must agree with the single-pass value."""
     run_sub(COMMON + """
     _, aux_ref = M.moe_ffn(p, x, spec, capacity_factor=8.0)
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         _, aux_ep = EP.moe_ffn_ep(p, x, spec, mesh=mesh, axis="model",
                                   capacity_factor=8.0)
     np.testing.assert_allclose(float(aux_ep), float(aux_ref), rtol=1e-5)
@@ -82,7 +83,7 @@ def test_ep_gradients_flow():
         _, aux = M.moe_ffn(p, x, spec, capacity_factor=8.0)
         return jnp.sum(out.astype(jnp.float32) ** 2) + 0.01 * aux
 
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         g_ep = jax.grad(loss_ep)(p, x)
     g_ref = jax.grad(loss_ref)(p, x)
     for k_ in ("w1", "w2", "w3", "router"):

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # bare env: deterministic seeded fallback (tier-1)
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.optim import adamw, compress
 

@@ -18,7 +18,7 @@ sees has exactly the contiguous physical width. Everything here pins that:
     readmitted through the normal queue up to `max_retries` times,
   * host-side invariants (refcounts never negative, the free list never
     double-frees, trie match == longest common prefix) swept generatively
-    under hypothesis or the deterministic fallback shim.
+    under hypothesis.
 """
 import collections
 import dataclasses
@@ -27,10 +27,7 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_smoke_config, with_swat
 from repro.core import model as Mod

@@ -48,7 +48,7 @@ COMMON = """
 @pytest.mark.slow
 def test_pp_loss_matches_single_pass():
     run_sub(COMMON + """
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         loss_fn = PP.make_pipeline_loss(cfg, pcfg, mesh)
         l_pp, m_pp = jax.jit(loss_fn)(params, batch)
     l_ref, m_ref = Mod.loss_fn(params, cfg, batch, remat=False)
@@ -64,7 +64,7 @@ def test_pp_grads_match_single_pass():
     """The autodiff-transposed reverse pipeline == plain backward, for every
     stage's blocks AND the pipe-replicated embed/head."""
     run_sub(COMMON + """
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         loss_fn = PP.make_pipeline_loss(cfg, pcfg, mesh)
         g_pp = jax.jit(jax.grad(lambda p, b: loss_fn(p, b)[0]))(params, batch)
     g_ref = jax.grad(lambda p: Mod.loss_fn(p, cfg, batch, remat=False)[0])(
@@ -90,7 +90,7 @@ def test_pp_train_step_runs_and_updates():
     from repro.optim import adamw
     opt_cfg = adamw.AdamWConfig(warmup_steps=1)
     opt = adamw.init_opt_state(params)
-    with mesh:  # legacy ambient-mesh context (jax.set_mesh needs newer jax)
+    with jax.set_mesh(mesh):
         step = jax.jit(PP.make_pp_train_step(cfg, opt_cfg, pcfg, mesh))
         p1, o1, metrics = step(params, opt, batch)
     assert np.isfinite(float(metrics["loss"]))

@@ -15,19 +15,12 @@ across randomized (window, num_global, lookahead, wrap-point, raggedness):
   * rollback safety: after insert-then-rollback, every garbage slot
     reconstructs to a position outside every live query's window (the
     no-resurrection guarantee speculative decode relies on).
-
-Runs under the real `hypothesis` when installed, else the deterministic
-tests/hypothesis_fallback.py shim (the CI spec-decode lane's mode).
 """
 import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.layers import _round_capacity
 from repro.core.types import AttentionSpec
@@ -69,7 +62,8 @@ def fifo_sim(geom: RingGeom, total: int):
     return pos, pos >= 0
 
 
-@settings(max_examples=40)
+# no deadline: the first example pays a jit compile
+@settings(deadline=None, max_examples=40)
 @given(geom=GEOMS, seed=st.integers(0, 10_000))
 def test_slot_positions_match_fifo_simulation(geom, seed):
     rng = np.random.RandomState(seed)
@@ -93,7 +87,8 @@ def test_slot_positions_match_fifo_simulation(geom, seed):
         assert not valid[b][geom.cap:].any(), (geom, total)
 
 
-@settings(max_examples=40)
+# no deadline: the first example pays a jit compile
+@settings(deadline=None, max_examples=40)
 @given(geom=GEOMS, t=st.integers(1, 6), seed=st.integers(0, 10_000))
 def test_ring_insert_matches_fifo_simulation(geom, t, seed):
     """Ragged multi-row insert == insert the rows one by one in numpy,
@@ -121,7 +116,8 @@ def test_ring_insert_matches_fifo_simulation(geom, t, seed):
     assert (got == want).all(), (geom, t, pos.tolist(), num_new.tolist())
 
 
-@settings(max_examples=25)
+# no deadline: the first example pays a jit compile
+@settings(deadline=None, max_examples=25)
 @given(geom=GEOMS, t=st.integers(1, 4), seed=st.integers(0, 10_000),
        causal=st.just(True))
 def test_fused_kernel_insert_matches_ref(geom, t, seed, causal):
@@ -160,7 +156,8 @@ def test_fused_kernel_insert_matches_ref(geom, t, seed, causal):
                                rtol=2e-4, atol=2e-4, err_msg=str((geom, t)))
 
 
-@settings(max_examples=40)
+# no deadline: the first example pays a jit compile
+@settings(deadline=None, max_examples=40)
 @given(geom=GEOMS, t=st.integers(1, 6), seed=st.integers(0, 10_000))
 def test_rollback_leaves_no_live_garbage(geom, t, seed):
     """The speculative rollback contract, stated on the ring alone: insert

@@ -1,8 +1,6 @@
 """Property tests for admission scheduling and block sizing.
 
-Runs under the real `hypothesis` when installed, else the deterministic
-tests/hypothesis_fallback.py shim (the CI spec-decode lane's mode). The
-properties pinned here are the resilience PR's admission invariants:
+The properties pinned here are the resilience PR's admission invariants:
 
   * `_block_len` over any mix of live budgets never overshoots the
     earliest completion (slots retire exactly at block boundaries, the
@@ -14,16 +12,9 @@ properties pinned here are the resilience PR's admission invariants:
     dropped on the floor (admitted + rejected == submitted).
 """
 import collections
-import sys
-from pathlib import Path
 
 import numpy as np
-
-sys.path.insert(0, str(Path(__file__).resolve().parent))
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # tier-1 bare env
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.scheduler import Scheduler

@@ -17,9 +17,9 @@ test_sharding_profiles.py pattern). The load-bearing claims:
     scheduler trims prefill batches to quantum multiples.
 
 Marked slow like every other subprocess suite, but still IN the CI fast
-lane: ci.yml runs this file as its own step (no marker filter — CPU-only,
-hypothesis-free), so a sharding regression is visible at a glance without
-double-running it inside the `-m "not slow"` sweep.
+lane: ci.yml runs this file as its own step (no marker filter — CPU-only),
+so a sharding regression is visible at a glance without double-running it
+inside the `-m "not slow"` sweep.
 """
 import os
 import subprocess
@@ -206,4 +206,22 @@ def test_chaos_quarantine_slot_parallel():
     kinds = [e["kind"] for e in Flt.consume_events()]
     assert "slot_quarantined" in kinds, kinds
     print("ok sharded chaos quarantine bitwise")
+    """)
+
+
+@pytest.mark.parametrize("dims", [(4, 1), (2, 2)], ids=["4x1", "2x2"])
+def test_pallas_decode_runs_per_shard(dims):
+    """The pallas decode kernel under a mesh runs inside shard_map (XLA
+    cannot partition a Mosaic kernel). Slot-parallel: bitwise the
+    single-device pallas engine. TP: every request served to budget."""
+    run_sub(COMMON + f"""
+    _, base = run(None, decode_impl="pallas")
+    eng, shard = run(mesh_lib.make_debug_mesh(*{dims!r}),
+                     decode_impl="pallas")
+    assert eng.stats["kernel_fallbacks"] == 0
+    if {dims!r}[1] == 1:
+        assert base == shard, (base, shard)
+    for i in range(6):
+        assert len(shard[i]) == budgets[i]
+    print("ok pallas per shard")
     """)
