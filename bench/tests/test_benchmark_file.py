@@ -1,0 +1,55 @@
+"""BENCHMARK.json is well formed and every name in it resolves to its
+file: configuration, traffic mix, checks and a reader per metric."""
+import json
+import re
+from pathlib import Path
+
+import pytest
+
+import run
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+
+def test_top_level_keys():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert 1 <= BENCH["run_seconds"] <= 51
+
+
+def test_names_and_files():
+    names = [c["name"] for c in BENCH["configs"]]
+    for c in BENCH["configs"]:
+        assert NAME.match(c["name"])
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["name"]) and w["config"] in names
+        assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").exists()
+        assert (ROOT / "bench" / "checks" / f"{w['name']}.json").exists()
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+
+
+@pytest.mark.parametrize("m", BENCH["end_to_end"] + BENCH["per_layer"],
+                         ids=lambda m: m["name"])
+def test_every_metric_has_a_reader(m):
+    assert NAME.match(m["name"])
+    assert callable(run.reader(m["name"]))
+    cells = {w["name"] for w in BENCH["workloads"]}
+    assert set(m.get("workloads", cells)) <= cells
+
+
+def test_every_cell_reports_setup_another_end_to_end_and_a_layer():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert e2e["setup_s"]["bound"] <= 0.25
+    for w in BENCH["workloads"]:
+        mine = [m["name"] for m in BENCH["end_to_end"]
+                if w["name"] in m.get("workloads", [w["name"]])]
+        assert "setup_s" in mine and len(mine) >= 2
+        layers = [m for m in BENCH["per_layer"]
+                  if w["name"] in m.get("workloads", [w["name"]])]
+        assert layers
+        for m in layers:
+            assert m["moves"] in mine
