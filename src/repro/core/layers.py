@@ -127,17 +127,21 @@ def _project_qkv(params, cfg: AttentionLayerCfg, x, kv_x):
 
 def attention_layer(params: Params, cfg: AttentionLayerCfg, x, *,
                     kv_x=None, positions=None, impl: str = "xla") -> jax.Array:
-    """Full-sequence attention (training / prefill). x: (B, L, Dm)."""
+    """Full-sequence attention (training / prefill). x: (B, L, Dm).
+    Scopes `qkv`, `attend`, `out`."""
     b, l, _ = x.shape
     kv_x = x if kv_x is None else kv_x
-    q, k, v = _project_qkv(params, cfg, x, kv_x)
-    if cfg.use_rope and not cfg.cross:
-        pos = (jnp.arange(l) if positions is None else positions)
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k = apply_rope(k, pos, cfg.rope_theta)
-    out = kops.swat_attention(q, k, v, cfg.spec, impl=impl)
-    out = out.transpose(0, 2, 1, 3).reshape(b, l, -1)
-    return out @ params["wo"]
+    with jax.named_scope("qkv"):
+        q, k, v = _project_qkv(params, cfg, x, kv_x)
+        if cfg.use_rope and not cfg.cross:
+            pos = (jnp.arange(l) if positions is None else positions)
+            q = apply_rope(q, pos, cfg.rope_theta)
+            k = apply_rope(k, pos, cfg.rope_theta)
+    with jax.named_scope("attend"):
+        out = kops.swat_attention(q, k, v, cfg.spec, impl=impl)
+    with jax.named_scope("out"):
+        out = out.transpose(0, 2, 1, 3).reshape(b, l, -1)
+        return out @ params["wo"]
 
 
 # KV cache ------------------------------------------------------------------
@@ -321,20 +325,27 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
     serve slots mid-flight at different positions. T > 1 (the speculative-
     decode verify primitive) needs a cache allocated with lookahead >= T-1
     so the step's own inserts never evict an in-window token. mesh: the
-    mesh the caller's jit is partitioned over (see `decode_attention`)."""
+    mesh the caller's jit is partitioned over (see `decode_attention`).
+
+    Scopes: `qkv`, `ring_write` (the ring insert; a paged cache's
+    write-back too), `attend` (a paged cache's gather too), `out`. The
+    pallas kernel inserts inside its attention pass, under `attend`."""
     b, t, _ = x.shape
-    q, k_new, v_new = _project_qkv(params, cfg, x, x)
     step = jnp.broadcast_to(jnp.asarray(cache["step"], jnp.int32), (b,))
-    if cfg.use_rope and not cfg.cross:
-        pos = step[:, None, None] + jnp.arange(t, dtype=jnp.int32)  # (B,1,T)
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k_new = apply_rope(k_new, pos, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _project_qkv(params, cfg, x, x)
+        if cfg.use_rope and not cfg.cross:
+            pos = (step[:, None, None]
+                   + jnp.arange(t, dtype=jnp.int32))          # (B,1,T)
+            q = apply_rope(q, pos, cfg.rope_theta)
+            k_new = apply_rope(k_new, pos, cfg.rope_theta)
     paged = "table" in cache
     if paged:
         # contiguous view of the block table; width == the contiguous
         # allocation, so everything below is bit-for-bit the ring path
-        k_arr = paged_gather(cache["pk"], cache["table"])
-        v_arr = paged_gather(cache["pv"], cache["table"])
+        with jax.named_scope("attend"):
+            k_arr = paged_gather(cache["pk"], cache["table"])
+            v_arr = paged_gather(cache["pv"], cache["table"])
     else:
         k_arr, v_arr = cache["k"], cache["v"]
     # rotate and mask at the LOGICAL capacity: the allocation may carry a
@@ -349,17 +360,19 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
     out, k_cache, v_cache = kops.decode_attention(
         q, k_arr, v_arr, None, cfg.spec, impl=impl,
         new_kv=(k_new, v_new), pos=step, ring_cap=cap, mesh=mesh)
-    out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
     if paged:
-        new_cache = {**cache,
-                     "pk": paged_scatter(cache["pk"], cache["table"],
-                                         k_cache),
-                     "pv": paged_scatter(cache["pv"], cache["table"],
-                                         v_cache),
-                     "step": step + t}
+        with jax.named_scope("ring_write"):
+            new_cache = {**cache,
+                         "pk": paged_scatter(cache["pk"], cache["table"],
+                                             k_cache),
+                         "pv": paged_scatter(cache["pv"], cache["table"],
+                                             v_cache),
+                         "step": step + t}
     else:
         new_cache = {"k": k_cache, "v": v_cache, "step": step + t}
-    return out @ params["wo"], new_cache
+    with jax.named_scope("out"):
+        out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+        return out @ params["wo"], new_cache
 
 
 def _dyn_update(cache, new, slot):
@@ -458,14 +471,39 @@ def attention_prefill_chunk(params: Params, cfg: AttentionLayerCfg, x, cache,
     pos0 may be a traced scalar (shared by all rows — the scheduler chunks
     the padded batch in lockstep); per-row raggedness comes from `lengths`:
     rows stop writing past their own length and their surplus outputs are
-    garbage the caller discards. Returns (output (B, T, Dm), new cache)."""
+    garbage the caller discards. Returns (output (B, T, Dm), new cache).
+    Scopes `qkv`, `attend`, `ring_write`, `out`."""
     assert cfg.spec.causal and not cfg.cross
     b, t, _ = x.shape
-    q, k_new, v_new = _project_qkv(params, cfg, x, x)
     pos = pos0 + jnp.arange(t, dtype=jnp.int32)            # (T,) absolute
-    if cfg.use_rope:
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k_new = apply_rope(k_new, pos, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _project_qkv(params, cfg, x, x)
+        if cfg.use_rope:
+            q = apply_rope(q, pos, cfg.rope_theta)
+            k_new = apply_rope(k_new, pos, cfg.rope_theta)
+    with jax.named_scope("attend"):
+        out = _chunk_attend(cfg, q, k_new, v_new, cache, pos, pos0, lengths,
+                            lookahead, x.dtype)
+    lens = jnp.asarray(lengths, jnp.int32)
+    cap = cache_capacity(cfg, cache["k"].shape[2], lookahead)
+    g = cfg.spec.num_global if cfg.spec.is_sparse else 0
+    with jax.named_scope("ring_write"):
+        write = pos[None, :] < lens[:, None]                   # (B, T)
+        new_cache = {
+            **cache,
+            "k": ring_scatter(cache["k"], k_new, pos, write, g, cap - g),
+            "v": ring_scatter(cache["v"], v_new, pos, write, g, cap - g),
+            "step": jnp.minimum(lens, pos0 + t).astype(jnp.int32),
+        }
+    with jax.named_scope("out"):
+        return out @ params["wo"], new_cache
+
+
+def _chunk_attend(cfg: AttentionLayerCfg, q, k_new, v_new, cache, pos, pos0,
+                  lengths, lookahead: int, dtype):
+    """A chunk's queries against the ring (earlier chunks) and the chunk
+    itself: (B, T, Hq * D) in `dtype`."""
+    b, t = q.shape[0], q.shape[2]
     cap_phys = cache["k"].shape[2]
     cap = cache_capacity(cfg, cap_phys, lookahead)  # logical: rot. modulus
     g = cfg.spec.num_global if cfg.spec.is_sparse else 0
@@ -517,17 +555,8 @@ def attention_prefill_chunk(params: Params, cfg: AttentionLayerCfg, x, cache,
     v_all = jnp.concatenate([cache["v"], v_new], axis=2)
     o = dots.einsum_f32("bhgtk,bhkd->bhgtd", (p / den).astype(v_all.dtype),
                         v_all)
-    out = (o.reshape(b, cfg.num_heads, t, d).astype(x.dtype)
-           .transpose(0, 2, 1, 3).reshape(b, t, -1))
-
-    write = pos[None, :] < lens[:, None]                   # (B, T)
-    new_cache = {
-        **cache,
-        "k": ring_scatter(cache["k"], k_new, pos, write, g, ring),
-        "v": ring_scatter(cache["v"], v_new, pos, write, g, ring),
-        "step": jnp.minimum(lens, pos0 + t).astype(jnp.int32),
-    }
-    return out @ params["wo"], new_cache
+    return (o.reshape(b, cfg.num_heads, t, d).astype(dtype)
+            .transpose(0, 2, 1, 3).reshape(b, t, -1))
 
 
 # ---------------------------------------------------------------- mlp ------

@@ -13,6 +13,12 @@ Layer kinds:
   xattn               - self-attn + cross-attn + FFN (whisper decoder)
 
 Three entry points per model: `loss_fn` (train), `prefill`, `decode_step`.
+
+Serving programs (`prefill`, `prefill_chunk`, `decode_step`) carry
+`jax.named_scope`s that a device trace reports with each operation:
+`embed`, then per layer `attn` (norm through the residual add; children
+`qkv`, `ring_write`, `attend`, `out`) and `mlp` or `moe` (norm through the
+residual add), then `unembed`. Scopes are metadata: they change no value.
 """
 from __future__ import annotations
 
@@ -192,29 +198,47 @@ def _stack_forward(blocks: Params, cfg: ModelConfig, x, pattern, *,
 
 
 def embed_tokens(params: Params, cfg: ModelConfig, batch: Dict[str, Any]):
-    if "embeddings" in batch:
-        # modality-frontend stub (VLM patches / audio frames): precomputed
-        # embeddings bypass the token table
-        x = batch["embeddings"].astype(_dtype(cfg))
-    elif cfg.embed_inputs:
-        x = jnp.take(params["embed"], batch["tokens"], axis=0)
-    else:
-        raise ValueError("batch needs 'tokens' or 'embeddings'")
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    if not cfg.use_rope:  # sinusoidal absolute positions (whisper)
-        x = x + L.sinusoidal_positions(x.shape[1], cfg.d_model
-                                       ).astype(x.dtype)[None]
-    return x
+    with jax.named_scope("embed"):
+        if "embeddings" in batch:
+            # modality-frontend stub (VLM patches / audio frames):
+            # precomputed embeddings bypass the token table
+            x = batch["embeddings"].astype(_dtype(cfg))
+        elif cfg.embed_inputs:
+            x = jnp.take(params["embed"], batch["tokens"], axis=0)
+        else:
+            raise ValueError("batch needs 'tokens' or 'embeddings'")
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        if not cfg.use_rope:  # sinusoidal absolute positions (whisper)
+            x = x + L.sinusoidal_positions(x.shape[1], cfg.d_model
+                                           ).astype(x.dtype)[None]
+        return x
 
 
 def _unembed(params: Params, cfg: ModelConfig, x):
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jax.lax.dot_general(
-        x, head, (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return L.softcap(logits, cfg.final_softcap)
+    with jax.named_scope("unembed"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jax.lax.dot_general(
+            x, head, (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return L.softcap(logits, cfg.final_softcap)
+
+
+def _ffn_serving(p, cfg: ModelConfig, x):
+    """The serving FFN sub-block, norm through the residual add, as scope
+    `moe` (dense-dispatch experts) or `mlp`; no FFN passes x through."""
+    if "moe" in p:
+        with jax.named_scope("moe"):
+            h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
+            y, _ = M.moe_ffn(p["moe"], h, cfg.moe, dispatch="dense")
+            return x + y
+    if "mlp" in p:
+        with jax.named_scope("mlp"):
+            h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
+            return x + L.mlp(p["mlp"], h)
+    return x
 
 
 def encode(params: Params, cfg: ModelConfig, batch):
@@ -362,34 +386,31 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int,
 def _apply_layer_decode(p, cfg, kind, x, cache, *, enc_out=None,
                         impl: str = "ref", lookahead: int = 0,
                         index: Optional[int] = None, mesh=None):
-    h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-    if kind.startswith("mamba"):
-        y, new_cache = S.mamba_decode(p["mixer"], h, cache, cfg.ssm)
-    else:
-        y, new_cache = L.attention_decode(p["mixer"],
-                                          attn_cfg(cfg, kind, index=index),
-                                          h, cache, impl=impl,
-                                          lookahead=lookahead, mesh=mesh)
-    x = x + y
-    if kind == "xattn":
-        h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
-        ccfg = attn_cfg(cfg, kind, cross=True)
-        q, _, _ = L._project_qkv(p["cross"], ccfg, h, h)
-        out = kops.decode_attention(
-            q, cache["xk"], cache["xv"],
-            jnp.full((x.shape[0], 1, 1, 1), cache["xk"].shape[2], jnp.int32),
-            ccfg.spec)
-        out = out.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], -1)
-        x = x + out @ p["cross"]["wo"]
-        new_cache = {**new_cache, "xk": cache["xk"], "xv": cache["xv"]}
-    if "moe" in p:
-        h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-        y, _ = M.moe_ffn(p["moe"], h, cfg.moe, dispatch="dense")
+    mamba = kind.startswith("mamba")
+    with jax.named_scope("mamba" if mamba else "attn"):
+        h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+        if mamba:
+            y, new_cache = S.mamba_decode(p["mixer"], h, cache, cfg.ssm)
+        else:
+            y, new_cache = L.attention_decode(
+                p["mixer"], attn_cfg(cfg, kind, index=index), h, cache,
+                impl=impl, lookahead=lookahead, mesh=mesh)
         x = x + y
-    elif "mlp" in p:
-        h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-        x = x + L.mlp(p["mlp"], h)
-    return x, new_cache
+    if kind == "xattn":
+        with jax.named_scope("xattn"):
+            h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
+            ccfg = attn_cfg(cfg, kind, cross=True)
+            q, _, _ = L._project_qkv(p["cross"], ccfg, h, h)
+            out = kops.decode_attention(
+                q, cache["xk"], cache["xv"],
+                jnp.full((x.shape[0], 1, 1, 1), cache["xk"].shape[2],
+                         jnp.int32),
+                ccfg.spec)
+            out = out.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1],
+                                                    -1)
+            x = x + out @ p["cross"]["wo"]
+        new_cache = {**new_cache, "xk": cache["xk"], "xv": cache["xv"]}
+    return _ffn_serving(p, cfg, x), new_cache
 
 
 def decode_step(params: Params, cfg: ModelConfig, batch, caches, *,
@@ -460,33 +481,32 @@ def prefill(params: Params, cfg: ModelConfig, batch, max_len: int, *,
         new_caches = {}
         for i, kind in enumerate(cfg.layer_pattern):
             p = blk_p[f"l{i}"]
-            h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-            if kind.startswith("mamba"):
-                y = S.mamba_block(p["mixer"], h, cfg.ssm,
-                                  chunk=cfg.ssm.chunk_size)
-                cache = _mamba_prefill_cache(p["mixer"], h, cfg,
-                                             lengths=lengths)
-            else:
-                acfg = attn_cfg(cfg, kind, index=i)
-                y = L.attention_layer(p["mixer"], acfg, h, impl=impl)
-                cache = L.prefill_kv_cache(p["mixer"], acfg, h, max_len,
-                                           lengths=lengths,
-                                           lookahead=lookahead)
-            x = x + y
-            if kind == "xattn":
-                h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
-                ccfg = attn_cfg(cfg, kind, cross=True)
-                x = x + L.attention_layer(p["cross"], ccfg, h, kv_x=enc_out,
-                                          impl=impl)
-                _, xk, xv = L._project_qkv(p["cross"], ccfg, enc_out, enc_out)
-                cache = {**cache, "xk": xk, "xv": xv}
-            if "moe" in p:
-                h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-                y, _ = M.moe_ffn(p["moe"], h, cfg.moe, dispatch="dense")
+            mamba = kind.startswith("mamba")
+            with jax.named_scope("mamba" if mamba else "attn"):
+                h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+                if mamba:
+                    y = S.mamba_block(p["mixer"], h, cfg.ssm,
+                                      chunk=cfg.ssm.chunk_size)
+                    cache = _mamba_prefill_cache(p["mixer"], h, cfg,
+                                                 lengths=lengths)
+                else:
+                    acfg = attn_cfg(cfg, kind, index=i)
+                    y = L.attention_layer(p["mixer"], acfg, h, impl=impl)
+                    with jax.named_scope("ring_write"):
+                        cache = L.prefill_kv_cache(
+                            p["mixer"], acfg, h, max_len, lengths=lengths,
+                            lookahead=lookahead)
                 x = x + y
-            elif "mlp" in p:
-                h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-                x = x + L.mlp(p["mlp"], h)
+            if kind == "xattn":
+                with jax.named_scope("xattn"):
+                    h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
+                    ccfg = attn_cfg(cfg, kind, cross=True)
+                    x = x + L.attention_layer(p["cross"], ccfg, h,
+                                              kv_x=enc_out, impl=impl)
+                    _, xk, xv = L._project_qkv(p["cross"], ccfg, enc_out,
+                                               enc_out)
+                cache = {**cache, "xk": xk, "xv": xv}
+            x = _ffn_serving(p, cfg, x)
             new_caches[f"l{i}"] = cache
         return (L.with_activation_constraint(x, act_sharding),), new_caches
 
@@ -544,18 +564,13 @@ def prefill_chunk(params: Params, cfg: ModelConfig, batch, caches, pos0,
         new_caches = {}
         for i, kind in enumerate(cfg.layer_pattern):
             p = blk_p[f"l{i}"]
-            h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-            y, nc = L.attention_prefill_chunk(
-                p["mixer"], attn_cfg(cfg, kind, index=i), h,
-                blk_cache[f"l{i}"], pos0, lengths, lookahead=lookahead)
-            x = x + y
-            if "moe" in p:
-                h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-                y, _ = M.moe_ffn(p["moe"], h, cfg.moe, dispatch="dense")
+            with jax.named_scope("attn"):
+                h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+                y, nc = L.attention_prefill_chunk(
+                    p["mixer"], attn_cfg(cfg, kind, index=i), h,
+                    blk_cache[f"l{i}"], pos0, lengths, lookahead=lookahead)
                 x = x + y
-            elif "mlp" in p:
-                h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-                x = x + L.mlp(p["mlp"], h)
+            x = _ffn_serving(p, cfg, x)
             new_caches[f"l{i}"] = nc
         return L.with_activation_constraint(x, act_sharding), new_caches
 

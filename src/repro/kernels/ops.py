@@ -351,7 +351,11 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
     the swat_decode flash kernel (the TPU hot path; interpret mode
     elsewhere). Ring order is irrelevant either way — softmax is
     permutation invariant. mesh: the mesh the caller's jit is partitioned
-    over; the pallas kernel then runs per shard (`_shard_decode_kernel`)."""
+    over; the pallas kernel then runs per shard (`_shard_decode_kernel`).
+
+    Named scopes: the ref impl's fused insert is `ring_write` and its
+    attention `attend`; the pallas kernel, which inserts inside its
+    attention pass, is `attend`."""
     b, _, t, _ = q.shape
     w_phys = k_cache.shape[2]
     cap = w_phys if ring_cap is None else int(ring_cap)
@@ -404,16 +408,19 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
             call, args = kern, (q, k_cache, v_cache, pos)
         if mesh is not None and mesh.size > 1:
             call = _shard_decode_kernel(call, mesh, k_cache.shape, fuse)
-        return call(*args)
+        with jax.named_scope("attend"):
+            return call(*args)
     if fuse:
         k_new, v_new = new_kv
-        k_cache = ref_impl.ring_insert_ref(k_cache, k_new, pos, nn,
-                                           ring_cap=cap, num_global=g)
-        v_cache = ref_impl.ring_insert_ref(v_cache, v_new, pos, nn,
-                                           ring_cap=cap, num_global=g)
-        out = ref_impl.decode_ref(q, k_cache, v_cache, None, spec,
-                                  scale=scale, total=pos + nn, q0=pos,
-                                  ring_cap=cap)
+        with jax.named_scope("ring_write"):
+            k_cache = ref_impl.ring_insert_ref(k_cache, k_new, pos, nn,
+                                               ring_cap=cap, num_global=g)
+            v_cache = ref_impl.ring_insert_ref(v_cache, v_new, pos, nn,
+                                               ring_cap=cap, num_global=g)
+        with jax.named_scope("attend"):
+            out = ref_impl.decode_ref(q, k_cache, v_cache, None, spec,
+                                      scale=scale, total=pos + nn, q0=pos,
+                                      ring_cap=cap)
         return out, k_cache, v_cache
     if t > 1 or (spec.is_sparse and spec.window
                  and cap > spec.window + 1 + g):

@@ -1,23 +1,18 @@
-"""swatscope CLI: inspect, validate, and profile serving telemetry.
+"""swatscope CLI: inspect and validate serving telemetry.
 
-Three subcommands, all off the hot path:
+Two subcommands, both off the hot path:
 
     # validate exported artifacts (the CI metrics lane)
     PYTHONPATH=src python -m repro.launch.scope validate \
         --trace /tmp/trace.json --metrics /tmp/metrics.prom
 
-    # per-shape kernel latency + analytic roofline rows
-    PYTHONPATH=src python -m repro.launch.scope profile \
-        --impl ref --window 16 --cap 64 --batch 2 --heads-kv 2
-
     # trace-time dispatch census of a smoke serve (which kernel shapes
     # did the engine actually compile?)
     PYTHONPATH=src python -m repro.launch.scope census --arch llama3.2-1b
 
-`validate` exits nonzero listing every schema problem; `profile` prints
-one row per shape (p50/p95 latency, FLOPs, HBM bytes, intensity);
-`census` runs a tiny instrumented serve and prints the deduped
-(shape -> traces) map plus the engine snapshot.
+`validate` exits nonzero listing every schema problem; `census` runs a
+tiny instrumented serve and prints the deduped (shape -> traces) map
+plus the engine snapshot.
 """
 import argparse
 import json
@@ -53,29 +48,6 @@ def _cmd_validate(args):
     for p in problems:
         print(f"[scope] INVALID: {p}")
     return 1 if problems else 0
-
-
-def _cmd_profile(args):
-    from repro.telemetry import kernelprof as KP
-
-    shape = {"b": args.batch, "h_kv": args.heads_kv, "group": args.group,
-             "t": args.tokens, "d": args.head_dim, "window": args.window,
-             "num_global": args.num_global, "cap": args.cap}
-    rows = KP.profile_decode([shape], impl=args.impl, iters=args.iters)
-    for r in rows:
-        print(f"[scope] {args.impl} b={r['b']} h_kv={r['h_kv']} "
-              f"g={r.get('group', 1)} t={r['t']} d={r['d']} "
-              f"window={r['window']} cap={r['cap']}: "
-              f"p50={r['p50_us']:.1f}us p95={r['p95_us']:.1f}us "
-              f"({r['flops'] / 1e6:.2f} MFLOP, "
-              f"{r['hbm_bytes'] / 1e6:.2f} MB, "
-              f"intensity={r['intensity']:.2f} flop/B, "
-              f"band={r['band_rows']} rows)")
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(rows, f, indent=2)
-        print(f"[scope] wrote {args.out}")
-    return 0
 
 
 def _cmd_census(args):
@@ -127,22 +99,6 @@ def main():
     v.add_argument("--metrics", default=None,
                    help="prometheus text (from serve --metrics-out)")
     v.set_defaults(fn=_cmd_validate)
-
-    p = sub.add_parser("profile", help="kernel latency + roofline rows")
-    p.add_argument("--impl", choices=("ref", "pallas"), default="ref")
-    p.add_argument("--batch", type=int, default=2)
-    p.add_argument("--heads-kv", type=int, default=2)
-    p.add_argument("--group", type=int, default=2,
-                   help="query heads per kv head (GQA group)")
-    p.add_argument("--tokens", type=int, default=1)
-    p.add_argument("--head-dim", type=int, default=64)
-    p.add_argument("--window", type=int, default=16)
-    p.add_argument("--num-global", type=int, default=4)
-    p.add_argument("--cap", type=int, default=64,
-                   help="physical ring rows (>= window+globals+tokens)")
-    p.add_argument("--iters", type=int, default=20)
-    p.add_argument("--out", default=None, help="write rows as JSON")
-    p.set_defaults(fn=_cmd_profile)
 
     c = sub.add_parser("census", help="trace-time dispatch census of a "
                                       "smoke serve")

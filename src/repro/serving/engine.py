@@ -119,6 +119,13 @@ class Result:
         return self.status == "ok"
 
 
+def _jit(name: str, fn, **kw):
+    """`jax.jit` under a stable program name: XLA names the module
+    `jit_<name>`, and a device trace reports it with every operation."""
+    fn.__name__ = name
+    return jax.jit(fn, **kw)
+
+
 class _Compiled:
     """Jitted functions shared by every engine over the same
     (cfg, max_len, decode_impl, top_k, mesh, profile, tokens_per_step):
@@ -279,14 +286,14 @@ class _Compiled:
                                    act_sharding=act,
                                    lookahead=self.lookahead)
             if self.mesh is None:
-                self._prefill_fns[n] = jax.jit(fn)
+                self._prefill_fns[n] = _jit("swat_prefill", fn)
             else:
                 vec = self.batch_sharding(self._sds((n,)), n)
                 tok_sh = self.batch_sharding(self._sds((n, 1)), n)
                 logit_sh = self.batch_sharding(
                     self._sds((n, 1, self.cfg.vocab_size), jnp.float32), n)
-                self._prefill_fns[n] = jax.jit(
-                    fn,
+                self._prefill_fns[n] = _jit(
+                    "swat_prefill", fn,
                     in_shardings=(self.param_sharding, tok_sh, vec),
                     out_shardings=(logit_sh, self.cache_sharding(n)))
         return self._prefill_fns[n]
@@ -300,15 +307,16 @@ class _Compiled:
             # copying it per chunk
             don = self._donate(1, 5)
             if self.mesh is None:
-                self._chunk_fns[n] = jax.jit(fn, donate_argnums=don)
+                self._chunk_fns[n] = _jit("swat_prefill_chunk", fn,
+                                          donate_argnums=don)
             else:
                 vec = self.batch_sharding(self._sds((n,)), n)
                 tok_sh = self.batch_sharding(self._sds((n, 1)), n)
                 logit_sh = self.batch_sharding(
                     self._sds((n, self.cfg.vocab_size), jnp.float32), n)
                 cache_sh = self.cache_sharding(n)
-                self._chunk_fns[n] = jax.jit(
-                    fn,
+                self._chunk_fns[n] = _jit(
+                    "swat_prefill_chunk", fn,
                     in_shardings=(self.param_sharding, cache_sh, tok_sh,
                                   self._rep, vec, logit_sh),
                     out_shardings=(logit_sh, cache_sh),
@@ -345,10 +353,11 @@ class _Compiled:
             # un-donated insert copies every cache at every admission
             don = self._donate(0)
             if self.mesh is None:
-                self._insert_fns[key] = jax.jit(fn, donate_argnums=don)
+                self._insert_fns[key] = _jit("swat_insert", fn,
+                                             donate_argnums=don)
             else:
-                self._insert_fns[key] = jax.jit(
-                    fn,
+                self._insert_fns[key] = _jit(
+                    "swat_insert", fn,
                     in_shardings=(self.cache_sharding(slots),
                                   self.cache_sharding(n), self._rep),
                     out_shardings=self.cache_sharding(slots),
@@ -359,7 +368,7 @@ class _Compiled:
         if n not in self._sample_fns:
             fn = functools.partial(sampling.sample, top_k=self.top_k)
             if self.mesh is None:
-                self._sample_fns[n] = jax.jit(fn)
+                self._sample_fns[n] = _jit("swat_sample", fn)
             else:
                 veci = self.batch_sharding(self._sds((n,)), n)
                 logit_sh = self.batch_sharding(
@@ -369,15 +378,17 @@ class _Compiled:
                 # sharded vector lowers to a pred[] all-reduce — the only
                 # collective left on the slot-parallel hot path. Replicated
                 # it folds to a local reduce.
-                self._sample_fns[n] = jax.jit(
-                    fn, in_shardings=(self._rep, logit_sh, self._rep),
+                self._sample_fns[n] = _jit(
+                    "swat_sample", fn,
+                    in_shardings=(self._rep, logit_sh, self._rep),
                     out_shardings=veci)
         return self._sample_fns[n]
 
     def fresh_caches(self, n: int):
         if n not in self._init_fns:
             out_sh = None if self.mesh is None else self.cache_sharding(n)
-            self._init_fns[n] = jax.jit(
+            self._init_fns[n] = _jit(
+                "swat_init_caches",
                 lambda: Mod.init_caches(self.cfg, n, self.max_len,
                                         lookahead=self.lookahead),
                 out_shardings=out_sh)
@@ -393,7 +404,8 @@ class _Compiled:
         if slots not in self._slot_init_fns:
             out_sh = (None if self.mesh is None
                       else self.slot_cache_sharding(slots))
-            self._slot_init_fns[slots] = jax.jit(
+            self._slot_init_fns[slots] = _jit(
+                "swat_init_slot_caches",
                 lambda: Mod.init_paged_caches(
                     self.cfg, slots, self.max_len, lookahead=self.lookahead,
                     shared_pool=self.mesh is None),
@@ -452,10 +464,11 @@ class _Compiled:
                 return out
             don = self._donate(0)
             if self.mesh is None:
-                self._insert_paged_fns[key] = jax.jit(fn, donate_argnums=don)
+                self._insert_paged_fns[key] = _jit(
+                    "swat_insert_paged", fn, donate_argnums=don)
             else:
-                self._insert_paged_fns[key] = jax.jit(
-                    fn,
+                self._insert_paged_fns[key] = _jit(
+                    "swat_insert_paged", fn,
                     in_shardings=(self.slot_cache_sharding(slots),
                                   self.cache_sharding(n), self._rep,
                                   self._rep),
@@ -489,11 +502,13 @@ class _Compiled:
                 return out
             don = self._donate(0)
             if self.mesh is None:
-                self._fixup_fns[key] = jax.jit(fn, donate_argnums=don)
+                self._fixup_fns[key] = _jit("swat_paged_fixup", fn,
+                                            donate_argnums=don)
             else:
                 sh = self.slot_cache_sharding(slots)
-                self._fixup_fns[key] = jax.jit(
-                    fn, in_shardings=(sh, self._rep, self._rep, self._rep),
+                self._fixup_fns[key] = _jit(
+                    "swat_paged_fixup", fn,
+                    in_shardings=(sh, self._rep, self._rep, self._rep),
                     out_shardings=sh, donate_argnums=don)
         return self._fixup_fns[key]
 
@@ -506,12 +521,13 @@ class _Compiled:
                 caches = jax.tree.map(lambda x: jnp.repeat(x, n, axis=1), c1)
                 return jnp.broadcast_to(lg, (n,) + lg.shape[1:]), caches
             if self.mesh is None:
-                self._bcast_fns[n] = jax.jit(fn)
+                self._bcast_fns[n] = _jit("swat_broadcast_prefix", fn)
             else:
                 logit_sh = self.batch_sharding(
                     self._sds((n, self.cfg.vocab_size), jnp.float32), n)
-                self._bcast_fns[n] = jax.jit(
-                    fn, out_shardings=(logit_sh, self.cache_sharding(n)))
+                self._bcast_fns[n] = _jit(
+                    "swat_broadcast_prefix", fn,
+                    out_shardings=(logit_sh, self.cache_sharding(n)))
         return self._bcast_fns[n]
 
     # ------------------------------------------------------------- decode --
@@ -612,7 +628,7 @@ class _Compiled:
         mx_arg = 9 + (1 if inject else 0)
         don = self._donate(1, mx_arg) if metrics else self._donate(1)
         if self.mesh is None:
-            return jax.jit(fn, donate_argnums=don)
+            return _jit("swat_decode_scan", fn, donate_argnums=don)
         cache_sh = self.slot_cache_sharding(slots)
         veci = self.batch_sharding(self._sds((slots,)), slots)
         vecb = self.batch_sharding(self._sds((slots,), jnp.bool_), slots)
@@ -621,8 +637,8 @@ class _Compiled:
         fin_in = (veci,) if inject else ()
         mx_in = ((MX.metrics_shardings(veci, self._rep),) if metrics
                  else ())
-        return jax.jit(
-            fn,
+        return _jit(
+            "swat_decode_scan", fn,
             in_shardings=(self.param_sharding, cache_sh, veci, vecb, veci,
                           vecf, self._rep, self._rep, vecb) + fin_in + mx_in,
             out_shardings=(cache_sh, veci, vecb, veci, self._rep, blk, blk,
@@ -820,7 +836,7 @@ class _Compiled:
         mx_arg = 11 + (1 if inject else 0)
         don = self._donate(1, mx_arg) if metrics else self._donate(1)
         if self.mesh is None:
-            return jax.jit(fn, donate_argnums=don)
+            return _jit("swat_spec_scan", fn, donate_argnums=don)
         cache_sh = self.slot_cache_sharding(slots)
         veci = self.batch_sharding(self._sds((slots,)), slots)
         vecb = self.batch_sharding(self._sds((slots,), jnp.bool_), slots)
@@ -832,8 +848,8 @@ class _Compiled:
         fin_in = (veci,) if inject else ()
         mx_in = ((MX.metrics_shardings(veci, self._rep),) if metrics
                  else ())
-        return jax.jit(
-            fn,
+        return _jit(
+            "swat_spec_scan", fn,
             in_shardings=(self.param_sharding, cache_sh, veci, vecb, veci,
                           vecf, self._rep, self._rep, hist_sh, veci,
                           vecb) + fin_in + mx_in,
@@ -966,9 +982,10 @@ class ServingEngine:
         programs; metrics=True tokens are bitwise identical (the
         test_telemetry.py contract).
         trace_capacity: ring-buffer depth of the always-on host-side
-        `self.tracer` (request lifecycle spans, decode-block spans, and
-        the unified degradation-event stream). Bounded memory forever —
-        O(trace_capacity), however long the engine serves."""
+        `self.tracer` (request lifecycle spans, decode-block spans, the
+        `swat.*` host spans, and the unified degradation-event stream).
+        Bounded memory forever — O(trace_capacity), however long the
+        engine serves."""
         self.cfg = cfg
         self.slots = batch_slots
         self.max_len = max_len
@@ -1008,10 +1025,17 @@ class ServingEngine:
         self.drafter = self._c.drafter
         self.params = (params if mesh is None
                        else jax.device_put(params, self._c.param_sharding))
+        # host-side lifecycle tracer (always on — O(1) Python per hook,
+        # zero device work) + its subscription to the unified degradation
+        # bus: the tracer's bounded `events` ring sees every record_event
+        # without consuming the bus (tests/benches still drain it)
+        self.tracer = Tracer(capacity=trace_capacity)
+        TEV.BUS.subscribe(self.tracer.on_bus_event)
         self.scheduler = Scheduler(
             max_prefill_tokens=max_prefill_tokens, pad_to=pad_to,
             slot_quantum=self._c.slot_quantum(batch_slots),
-            max_prompt_len=max_prompt_len, vocab_size=cfg.vocab_size)
+            max_prompt_len=max_prompt_len, vocab_size=cfg.vocab_size,
+            tracer=self.tracer)
         self.max_pending = max_pending
         self.spec_min_acceptance = float(spec_min_acceptance)
         self.spec_resume_acceptance = float(
@@ -1059,12 +1083,6 @@ class ServingEngine:
         self._mx: Optional[Dict[str, Any]] = None
         if self.metrics:
             self._stage_metrics()
-        # host-side lifecycle tracer (always on — O(1) Python per hook,
-        # zero device work) + its subscription to the unified degradation
-        # bus: the tracer's bounded `events` ring sees every record_event
-        # without consuming the bus (tests/benches still drain it)
-        self.tracer = Tracer(capacity=trace_capacity)
-        TEV.BUS.subscribe(self.tracer.on_bus_event)
         # decode telemetry (accumulated across run()/step() calls):
         # spec_steps counts executed verify dispatches, draft_proposed /
         # draft_accepted count drafts offered vs kept (acceptance_rate).
@@ -1075,7 +1093,13 @@ class ServingEngine:
         # Results — exactly once per request, whatever max_retries did.
         # The resilience counters mirror the degradation-event bus
         # (faults.consume_events) so a bench/test can assert "nothing
-        # degraded" from either side.
+        # degraded" from either side. Work at the layer boundaries, the
+        # same counts the swat.admit / swat.decode spans carry:
+        # prefill_tokens_computed counts real prompt tokens prefilled,
+        # prefill_tokens_padded the tokens the device computed for them
+        # (rows x padded length); decode_steps counts the steps decode
+        # blocks ran, decode_slot_steps those steps times the slot count
+        # (every slot computes every step, live or not).
         self.stats = {"spec_steps": 0, "draft_proposed": 0,
                       "draft_accepted": 0, "tokens_emitted": 0,
                       "tokens_delivered": 0,
@@ -1083,7 +1107,10 @@ class ServingEngine:
                       "failed": 0, "kernel_fallbacks": 0,
                       "spec_autodisable": 0, "spec_resume": 0,
                       "readmitted": 0, "prefill_tokens_computed": 0,
-                      "prefill_prefix_shared": 0, "cow_moves": 0}
+                      "prefill_tokens_padded": 0,
+                      "prefill_prefix_shared": 0, "cow_moves": 0,
+                      "decode_blocks": 0, "decode_steps": 0,
+                      "decode_slot_steps": 0}
 
     @property
     def acceptance_rate(self) -> float:
@@ -1226,62 +1253,84 @@ class ServingEngine:
 
     # ------------------------------------------------------------ prefill --
     def _prefill_into(self, plan: PrefillPlan, slots: List[int]):
-        self.tracer.on_admit([r.rid for r in plan.requests])
+        """Prefill one planned batch into free slots, as a `swat.admit`
+        host span (rows, padded_len, prompt_tokens: real tokens computed,
+        padded_tokens: tokens the device computes) with children
+        `prefill`, `sample` (through the wait for the first tokens) and
+        `insert` (the cache insert)."""
         n, l_pad = plan.tokens.shape
-        tokens = jnp.asarray(plan.tokens)
-        lengths = jnp.asarray(plan.lengths)
         prefix = 0
         if (self.share_prefix and n >= 2 and self.prefill_chunk
                 and plan.prefix_len >= self.share_min_prefix):
             prefix = int(plan.prefix_len)
-        if prefix:
-            # prefill the shared prefix ONCE on one row, broadcast the
-            # cache to every admitted row, then walk only the per-row
-            # suffixes — prefill compute drops from sum(len_i) to
-            # prefix + sum(len_i - prefix)
-            out1, c1 = self._c.prefill(1)(
-                self.params, tokens[:1, :prefix],
-                jnp.full((1,), prefix, jnp.int32))
-            last, caches = self._c.broadcast_prefix(n)(c1, out1[:, 0])
-            for p in range(prefix, l_pad, self.prefill_chunk):
-                chunk = tokens[:, p:p + self.prefill_chunk]
-                last, caches = self._c.chunk(n)(
-                    self.params, caches, chunk, jnp.int32(p), lengths, last)
-            logits = last
-            self.stats["prefill_prefix_shared"] += 1
-            self.stats["prefill_tokens_computed"] += prefix + int(
-                sum(max(int(l) - prefix, 0) for l in plan.lengths))
-        elif self.prefill_chunk and l_pad > self.prefill_chunk:
-            caches = self._c.fresh_caches(n)
-            last = jnp.zeros((n, self.cfg.vocab_size), jnp.float32)
-            for p in range(0, l_pad, self.prefill_chunk):
-                chunk = tokens[:, p:p + self.prefill_chunk]
-                last, caches = self._c.chunk(n)(
-                    self.params, caches, chunk, jnp.int32(p), lengths, last)
-            logits = last
-            self.stats["prefill_tokens_computed"] += int(
-                sum(int(l) for l in plan.lengths))
-        else:
-            out, caches = self._c.prefill(n)(self.params, tokens, lengths)
-            logits = out[:, 0]
-            self.stats["prefill_tokens_computed"] += int(
-                sum(int(l) for l in plan.lengths))
-        temps = np.asarray([r.temperature for r in plan.requests], np.float32)
-        self.key, sub = jax.random.split(self.key)
-        first = np.asarray(self._c.sample(n)(sub, logits, jnp.asarray(temps)))
+        real = prefix + sum(max(int(l) - prefix, 0) for l in plan.lengths)
+        padded = prefix + n * (l_pad - prefix)
+        self.stats["prefill_tokens_computed"] += real
+        self.stats["prefill_tokens_padded"] += padded
+        with self.tracer.span("swat.admit", rows=n, padded_len=l_pad,
+                              prompt_tokens=real, padded_tokens=padded):
+            self._admit_batch(plan, slots, prefix)
+
+    def _admit_batch(self, plan: PrefillPlan, slots: List[int],
+                     prefix: int):
+        """The body of `_prefill_into`: prefill, first tokens, insert, and
+        the slots' host state."""
+        span = self.tracer.span
+        self.tracer.on_admit([r.rid for r in plan.requests])
+        n, l_pad = plan.tokens.shape
+        with span("swat.admit.prefill"):
+            tokens = jnp.asarray(plan.tokens)
+            lengths = jnp.asarray(plan.lengths)
+            if prefix:
+                # prefill the shared prefix ONCE on one row, broadcast the
+                # cache to every admitted row, then walk only the per-row
+                # suffixes — prefill compute drops from sum(len_i) to
+                # prefix + sum(len_i - prefix)
+                out1, c1 = self._c.prefill(1)(
+                    self.params, tokens[:1, :prefix],
+                    jnp.full((1,), prefix, jnp.int32))
+                last, caches = self._c.broadcast_prefix(n)(c1, out1[:, 0])
+                for p in range(prefix, l_pad, self.prefill_chunk):
+                    chunk = tokens[:, p:p + self.prefill_chunk]
+                    last, caches = self._c.chunk(n)(
+                        self.params, caches, chunk, jnp.int32(p), lengths,
+                        last)
+                logits = last
+                self.stats["prefill_prefix_shared"] += 1
+            elif self.prefill_chunk and l_pad > self.prefill_chunk:
+                caches = self._c.fresh_caches(n)
+                last = jnp.zeros((n, self.cfg.vocab_size), jnp.float32)
+                for p in range(0, l_pad, self.prefill_chunk):
+                    chunk = tokens[:, p:p + self.prefill_chunk]
+                    last, caches = self._c.chunk(n)(
+                        self.params, caches, chunk, jnp.int32(p), lengths,
+                        last)
+                logits = last
+            else:
+                out, caches = self._c.prefill(n)(self.params, tokens,
+                                                  lengths)
+                logits = out[:, 0]
+        with span("swat.admit.sample"):
+            temps = np.asarray([r.temperature for r in plan.requests],
+                               np.float32)
+            self.key, sub = jax.random.split(self.key)
+            first = np.asarray(self._c.sample(n)(sub, logits,
+                                                 jnp.asarray(temps)))
         self.tracer.on_first_token([r.rid for r in plan.requests])
-        if self._paged is not None:
-            # shareability is judged against EVERY position prefill wrote —
-            # padded rows carry (masked) garbage up to l_pad, so the
-            # conservative write-span per row is [prefix, l_pad)
-            self._paged.admit(slots, [l_pad] * n, prefix_len=prefix)
-            ptables = {f"l{i}": jnp.asarray(t)
-                       for i, t in self._paged.tables.items()}
-            self.caches = self._c.insert_paged(self.slots, n)(
-                self.caches, caches, jnp.asarray(slots, jnp.int32), ptables)
-        else:
-            self.caches = self._c.insert(self.slots, n)(
-                self.caches, caches, jnp.asarray(slots, jnp.int32))
+        with span("swat.admit.insert"):
+            if self._paged is not None:
+                # shareability is judged against EVERY position prefill
+                # wrote — padded rows carry (masked) garbage up to l_pad, so
+                # the conservative write-span per row is [prefix, l_pad)
+                self._paged.admit(slots, [l_pad] * n, prefix_len=prefix)
+                ptables = {f"l{i}": jnp.asarray(t)
+                           for i, t in self._paged.tables.items()}
+                self.caches = self._c.insert_paged(self.slots, n)(
+                    self.caches, caches, jnp.asarray(slots, jnp.int32),
+                    ptables)
+            else:
+                self.caches = self._c.insert(self.slots, n)(
+                    self.caches, caches, jnp.asarray(slots, jnp.int32))
         for s, l in zip(slots, plan.lengths):
             self._slot_pos[s] = int(l)
         for i, (req, s) in enumerate(zip(plan.requests, slots)):
@@ -1512,6 +1561,12 @@ class ServingEngine:
         finished slots. Speculative engines run n draft/verify/accept
         steps instead, each emitting 1..speculative+1 tokens per slot.
 
+        Host spans: `swat.decode` (n, slots, live) around the block, with
+        children `stage` (host work before dispatch: restaging the slot
+        vectors and placing them), `dispatch` (the scan call until it
+        returns), `sync` (the wait for its tokens) and `retire`
+        (everything after; `emitted`, the tokens the block emitted).
+
         Resilience: the compiled scan body carries a per-slot `poisoned`
         flag — a slot whose logits go non-finite stops emitting on device
         and is QUARANTINED here (finalized as status "poisoned", slot
@@ -1520,6 +1575,80 @@ class ServingEngine:
         live = [s for s in range(self.slots) if not self.slot_free[s]]
         if not live:
             return []
+        span = self.tracer.span
+        with span("swat.decode", n=n, slots=self.slots,
+                  live=len(live)) as blk:
+            with span("swat.decode.stage"):
+                use_spec, probe, inject = self._stage_block(n, live)
+            dev = self._dev
+            # steady-state guard: every operand is device-resident by now,
+            # so the block dispatch must not transfer ANYTHING implicitly —
+            # a host-synced scalar or np-array operand inside this loop
+            # taxes every block and is exactly what the analyzer's
+            # host-sync rule lints for. Explicit syncs (np.asarray on the
+            # outputs below) stay legal under "disallow".
+            guard = (jax.transfer_guard("disallow") if self.transfer_guard
+                     else contextlib.nullcontext())
+            extra = (dev["fin"],) if inject else ()
+            # metrics carry rides LAST (donated); its updated pytree comes
+            # back last and is simply re-fed next block — no host sync here
+            mextra = (self._mx,) if self.metrics else ()
+            try:
+                if use_spec:
+                    with span("swat.decode.dispatch"), guard:
+                        outs = self._c.spec_scan(n, self.slots)(
+                            self.params, self.caches, dev["tok"],
+                            dev["active"], dev["budget"], dev["temps"],
+                            dev["anyt"], self.key, dev["hist"], dev["hcnt"],
+                            dev["poisoned"], *extra, *mextra)
+                    (self.caches, tok, active_out, budget, self.key, hist,
+                     hcnt, toks, emit, steps, poisoned) = outs[:11]
+                    if inject:
+                        dev["fin"] = outs[11]
+                    if self.metrics:
+                        self._mx = outs[11 + (1 if inject else 0)]
+                    # drafter state stays device-resident too;
+                    # _prefill_into materializes to numpy only when it
+                    # needs to seed a row
+                    self.slot_hist = hist
+                    self.slot_hcnt = hcnt
+                    dev.update(tok=tok, active=active_out, budget=budget,
+                               hist=hist, hcnt=hcnt, poisoned=poisoned)
+                else:
+                    with span("swat.decode.dispatch"), guard:
+                        outs = self._c.scan(n, self.slots)(
+                            self.params, self.caches, dev["tok"],
+                            dev["active"], dev["budget"], dev["temps"],
+                            dev["anyt"], self.key, dev["poisoned"], *extra,
+                            *mextra)
+                    (self.caches, tok, active_out, budget, self.key, toks,
+                     emit, poisoned) = outs[:8]
+                    if inject:
+                        dev["fin"] = outs[8]
+                    if self.metrics:
+                        self._mx = outs[8 + (1 if inject else 0)]
+                    dev.update(tok=tok, active=active_out, budget=budget,
+                               poisoned=poisoned)
+                # the block's host sync: the span from dispatch to here
+                # covers dispatch + device execution + drain
+                with span("swat.decode.sync"):
+                    toks, emit = np.asarray(toks), np.asarray(emit)
+            except F.KernelDispatchError as e:
+                return self._kernel_fallback(e, n)
+            emitted = int(emit.sum())
+            with span("swat.decode.retire", emitted=emitted):
+                done = self._retire_block(n, live, use_spec, probe, toks,
+                                          emit, tok, budget, poisoned,
+                                          steps if use_spec else n)
+        self.tracer.on_block("spec" if use_spec else "seq", n, blk.t0,
+                             emitted, blk.t1)
+        return done
+
+    def _stage_block(self, n: int, live: List[int]):
+        """Host work before a block's dispatch: chaos cache poisons, paged
+        copy-on-write, drafter history, and (re)staging the per-slot
+        vectors on device. Returns (speculative?, ladder probe?, logit
+        faults compiled in?)."""
         self._apply_cache_poisons(live)
         if self._paged is not None:
             self._paged_sync(n)
@@ -1563,78 +1692,34 @@ class ServingEngine:
                 sh = self._c.slot_vector_shardings(self.slots)
                 self._dev = {k: jax.device_put(v, sh[k])
                              for k, v in self._dev.items()}
-        dev = self._dev
         if self.mesh is not None:
             # admission splits self.key on host (default placement); the
             # scan consumes it replicated — re-place explicitly (no-op
             # between consecutive blocks: scan outputs carry _rep already)
             self.key = jax.device_put(self.key, self._c._rep)
-        # steady-state guard: every operand is device-resident by now, so
-        # the block dispatch must not transfer ANYTHING implicitly — a
-        # host-synced scalar or np-array operand inside this loop taxes
-        # every block and is exactly what the analyzer's host-sync rule
-        # lints for. Explicit syncs (np.asarray on the outputs below) stay
-        # legal under "disallow".
-        guard = (jax.transfer_guard("disallow") if self.transfer_guard
-                 else contextlib.nullcontext())
-        extra = (dev["fin"],) if inject else ()
-        # metrics carry rides LAST (donated); its updated pytree comes
-        # back last and is simply re-fed next block — no host sync here
-        mextra = (self._mx,) if self.metrics else ()
-        t0 = self.tracer.clock()
-        try:
-            if use_spec:
-                with guard:
-                    outs = self._c.spec_scan(n, self.slots)(
-                        self.params, self.caches, dev["tok"], dev["active"],
-                        dev["budget"], dev["temps"], dev["anyt"], self.key,
-                        dev["hist"], dev["hcnt"], dev["poisoned"],
-                        *extra, *mextra)
-                (self.caches, tok, active_out, budget, self.key, hist, hcnt,
-                 toks, emit, steps, poisoned) = outs[:11]
-                if inject:
-                    dev["fin"] = outs[11]
-                if self.metrics:
-                    self._mx = outs[11 + (1 if inject else 0)]
-                # drafter state stays device-resident too; _prefill_into
-                # materializes to numpy only when it needs to seed a row
-                self.slot_hist = hist
-                self.slot_hcnt = hcnt
-                dev.update(tok=tok, active=active_out, budget=budget,
-                           hist=hist, hcnt=hcnt, poisoned=poisoned)
-                toks, emit = np.asarray(toks), np.asarray(emit)
-                counts = emit.sum(axis=-1)                    # (n, slots)
-                ran = counts >= 1
-                self.stats["spec_steps"] += int(steps)
-                prop = self.speculative * int(ran.sum())
-                acc = int((counts[ran] - 1).sum())
-                self.stats["draft_proposed"] += prop
-                self.stats["draft_accepted"] += acc
-                self._spec_ladder_update(prop, acc, probe)
-            else:
-                with guard:
-                    outs = self._c.scan(n, self.slots)(
-                        self.params, self.caches, dev["tok"], dev["active"],
-                        dev["budget"], dev["temps"], dev["anyt"], self.key,
-                        dev["poisoned"], *extra, *mextra)
-                (self.caches, tok, active_out, budget, self.key, toks,
-                 emit, poisoned) = outs[:8]
-                if inject:
-                    dev["fin"] = outs[8]
-                if self.metrics:
-                    self._mx = outs[8 + (1 if inject else 0)]
-                dev.update(tok=tok, active=active_out, budget=budget,
-                           poisoned=poisoned)
-                toks, emit = np.asarray(toks), np.asarray(emit)
-                if self.speculative:
-                    self._hist_stale = True   # drafter history lags output
-        except F.KernelDispatchError as e:
-            return self._kernel_fallback(e, n)
+        return use_spec, probe, inject
+
+    def _retire_block(self, n, live, use_spec, probe, toks, emit, tok,
+                      budget, poisoned, steps) -> List[Result]:
+        """Host work after a block's sync: counters, the speculative
+        ladder, the paged position mirror, and finalizing finished or
+        quarantined slots. `steps`: the steps the device ran."""
+        steps = int(steps)
+        if use_spec:
+            counts = emit.sum(axis=-1)                    # (n, slots)
+            ran = counts >= 1
+            self.stats["spec_steps"] += steps
+            prop = self.speculative * int(ran.sum())
+            acc = int((counts[ran] - 1).sum())
+            self.stats["draft_proposed"] += prop
+            self.stats["draft_accepted"] += acc
+            self._spec_ladder_update(prop, acc, probe)
+        elif self.speculative:
+            self._hist_stale = True   # drafter history lags output
         self.stats["tokens_emitted"] += int(emit.sum())
-        # the np.asarray(emit) above IS the block's host sync — the span
-        # closed here covers dispatch + device execution + drain
-        self.tracer.on_block("spec" if use_spec else "seq", n, t0,
-                             int(emit.sum()))
+        self.stats["decode_blocks"] += 1
+        self.stats["decode_steps"] += steps
+        self.stats["decode_slot_steps"] += steps * self.slots
         if self._paged is not None:
             # advance the per-slot ring-write position mirror: sequential
             # steps write one row per executed step unconditionally (+n);

@@ -44,22 +44,26 @@ def sample(key, logits, temperatures, top_k: int = 0, any_sampling=None):
     same value the slow path's final `where` picks), so the branch is
     bitwise-invisible — it exists because on small models the sampling op
     chain costs as much as the forward pass it follows, and the speculative
-    verify runs it at every one of T positions."""
-    logits = logits.astype(jnp.float32)
-    temperatures = jnp.asarray(temperatures, jnp.float32)
+    verify runs it at every one of T positions.
 
-    def greedy_only(lg):
-        return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    Its operations carry the named scope `sample`."""
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        temperatures = jnp.asarray(temperatures, jnp.float32)
 
-    def full(lg):
-        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        if top_k and top_k < lg.shape[-1]:
-            kth = jax.lax.top_k(lg, top_k)[0][:, -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        scaled = lg / jnp.maximum(temperatures[:, None], 1e-6)
-        drawn = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-        return jnp.where(temperatures > 0, drawn, greedy)
+        def greedy_only(lg):
+            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
-    if any_sampling is None:
-        any_sampling = jnp.any(temperatures > 0)
-    return jax.lax.cond(any_sampling, full, greedy_only, logits)
+        def full(lg):
+            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            if top_k and top_k < lg.shape[-1]:
+                kth = jax.lax.top_k(lg, top_k)[0][:, -1:]
+                lg = jnp.where(lg < kth, -jnp.inf, lg)
+            scaled = lg / jnp.maximum(temperatures[:, None], 1e-6)
+            drawn = jax.random.categorical(key, scaled, axis=-1).astype(
+                jnp.int32)
+            return jnp.where(temperatures > 0, drawn, greedy)
+
+        if any_sampling is None:
+            any_sampling = jnp.any(temperatures > 0)
+        return jax.lax.cond(any_sampling, full, greedy_only, logits)

@@ -15,9 +15,13 @@ slot-axis size) makes admission divisibility-aware: whenever more than one
 quantum of prompts is available, the batch is trimmed to a quantum multiple
 (the remainder stays queued for the next — also sharded — batch). A final
 sub-quantum batch still admits, so nothing ever starves.
+
+Given the engine's tracer, each planning pass is a `swat.plan` host span
+(argument `queue`: the queue depth it started from).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Deque, List, Optional, Tuple
 
@@ -47,7 +51,7 @@ class PrefillPlan:
 class Scheduler:
     def __init__(self, *, max_prefill_tokens: int = 8192, pad_to: int = 16,
                  slot_quantum: int = 1, max_prompt_len: Optional[int] = None,
-                 vocab_size: Optional[int] = None):
+                 vocab_size: Optional[int] = None, tracer=None):
         """max_prompt_len / vocab_size: optional admission validation
         bounds. A request that violates one is REJECTED — popped off the
         queue into `take_rejected()` with a reason, never raised: one
@@ -55,7 +59,8 @@ class Scheduler:
         whole engine loop, losing every in-flight slot. max_prompt_len=None
         keeps long prompts admissible (the ring prefill serves them exactly
         — only the last window survives, as it should); set it when the
-        deployment wants oversized prompts refused instead."""
+        deployment wants oversized prompts refused instead. tracer: a
+        `telemetry.Tracer` that records each plan as a `swat.plan` span."""
         assert pad_to >= 1 and max_prefill_tokens >= pad_to
         assert slot_quantum >= 1
         self.max_prefill_tokens = max_prefill_tokens
@@ -63,6 +68,7 @@ class Scheduler:
         self.slot_quantum = slot_quantum
         self.max_prompt_len = max_prompt_len
         self.vocab_size = vocab_size
+        self.tracer = tracer
         self._rejected: List[Tuple[object, str]] = []
 
     def _bucket(self, n: int) -> int:
@@ -102,6 +108,11 @@ class Scheduler:
         are popped into `take_rejected()` and never poison the batch."""
         if not pending or num_free <= 0:
             return None
+        with (self.tracer.span("swat.plan", queue=len(pending))
+              if self.tracer is not None else contextlib.nullcontext()):
+            return self._plan(pending, num_free)
+
+    def _plan(self, pending: Deque, num_free: int) -> Optional[PrefillPlan]:
         take: List = []
         flat: List[np.ndarray] = []
         longest = 0

@@ -15,14 +15,17 @@ path computes or how it runs:
   host     `tracer.py` + `events.py` — a ring-buffered Tracer recording
            per-Request lifecycle spans (submit -> queued -> admitted ->
            prefill -> decode blocks -> done/degraded) with TTFT / TPOT /
-           queue-delay, fed by the unified degradation-event bus that
+           queue-delay, the engine's `swat.*` host spans (profiler
+           annotations on the device trace's clock, with their counts),
+           fed by the unified degradation-event bus that
            `serving/faults.py` now delegates to (one event stream, not
            two). Exports Chrome-trace JSON and a Prometheus-style text
            exposition (`ServingEngine.metrics_text()`).
   kernel   `kernelprof.py` — opt-in dispatch census (trace-time, zero
            runtime cost) + analytic FLOP/byte roofline from the banded
-           decode geometry + a block-latency sampler; the data feed for
-           the shape-adaptive-dispatch roadmap item.
+           decode geometry; the data feed for the shape-adaptive-dispatch
+           roadmap item. Device times come from a profiler trace of the
+           served path, read by the engine's named scopes and programs.
 
 `validate.py` schema-checks the exported artifacts (the CI metrics lane);
 `repro.launch.scope` pretty-prints a live engine snapshot.

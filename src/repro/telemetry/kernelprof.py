@@ -1,6 +1,6 @@
 """Kernel profiling hooks (swatscope layer 3) — opt-in, never hot-path.
 
-Three tools that feed the shape-adaptive-dispatch roadmap item with real
+Two tools that feed the shape-adaptive-dispatch roadmap item with real
 data instead of guesswork:
 
   dispatch census   `kernels/ops.py` / `swat_decode.py` call
@@ -16,17 +16,15 @@ data instead of guesswork:
                     physical ring rows a decode step actually touches;
                     intensity = flops/bytes locates each shape on the
                     roofline.
-  latency sampler   `sample_latency()` / `profile_decode()` — standalone
-                    timed dispatches (block_until_ready, medians over
-                    iters) for per-shape block-latency rows, the same
-                    measurement discipline as benchmarks/common.time_fn.
+
+Times come from a device trace of the served path (the engine's
+`swat.*` host spans and named scopes), not from here.
 
 Census state is module-global and OFF by default — `enable_census()` in
 a `try/finally` like `faults.install_kernel_failure`.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 _CENSUS_ON = False
@@ -93,71 +91,3 @@ def banded_decode_cost(*, b: int, h_q: int, h_kv: int, t: int, d: int,
         bytes_ += 2 * b * h_kv * t * d * dtype_bytes    # ring insert
     return {"flops": float(flops), "hbm_bytes": float(bytes_),
             "intensity": float(flops) / float(bytes_), "band_rows": band}
-
-
-# ------------------------------------------------------- latency sampling --
-
-def sample_latency(fn, *args, iters: int = 30, warmup: int = 3
-                   ) -> Dict[str, float]:
-    """Block-latency samples of one jitted dispatch: median / p95 /
-    best, in microseconds. Synchronizes every call (block_until_ready) —
-    which is exactly why this lives OUTSIDE the engine hot path."""
-    import jax
-    import numpy as np
-    for _ in range(max(1, warmup)):
-        jax.block_until_ready(fn(*args))
-    ts = []
-    for _ in range(max(1, iters)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        ts.append((time.perf_counter() - t0) * 1e6)
-    arr = np.asarray(ts)
-    return {"p50_us": float(np.percentile(arr, 50)),
-            "p95_us": float(np.percentile(arr, 95)),
-            "best_us": float(arr.min()), "iters": int(arr.size)}
-
-
-def profile_decode(shapes: List[Dict[str, int]], *, impl: str = "ref",
-                   interpret: Optional[bool] = None, iters: int = 20,
-                   seed: int = 0) -> List[Dict[str, Any]]:
-    """Per-shape block-latency + roofline rows for the fused decode op.
-
-    Each shape dict: {b, h_kv, group, t, d, window, num_global, cap}
-    (cap = physical ring rows; must satisfy the fused-insert geometry,
-    cap >= window + globals + t for windowed specs). Returns one row per
-    shape merging measured latency with the analytic cost — the
-    autotune-table feedstock."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.core.types import AttentionSpec
-    from repro.kernels import ops
-
-    rows: List[Dict[str, Any]] = []
-    rng = np.random.RandomState(seed)
-    for sh in shapes:
-        b, h_kv, group = sh["b"], sh["h_kv"], sh.get("group", 1)
-        t, d, cap = sh.get("t", 1), sh["d"], sh["cap"]
-        window, g = sh.get("window", 0), sh.get("num_global", 0)
-        h_q = h_kv * group
-        spec = (AttentionSpec(kind="swat", window=window, num_global=g)
-                if window else AttentionSpec(kind="dense"))
-        q = jnp.asarray(rng.randn(b, h_q, t, d), jnp.float32)
-        kc = jnp.asarray(rng.randn(b, h_kv, cap, d), jnp.bfloat16)
-        vc = jnp.asarray(rng.randn(b, h_kv, cap, d), jnp.bfloat16)
-        nk = jnp.asarray(rng.randn(b, h_kv, t, d), jnp.bfloat16)
-        nv = jnp.asarray(rng.randn(b, h_kv, t, d), jnp.bfloat16)
-        pos = jnp.full((b,), max(cap - t, g), jnp.int32)
-
-        fn = jax.jit(lambda q, kc, vc, nk, nv, pos: ops.decode_attention(
-            q, kc, vc, None, spec, impl=impl, interpret=interpret,
-            new_kv=(nk, nv), pos=pos, ring_cap=cap)[0])
-        lat = sample_latency(fn, q, kc, vc, nk, nv, pos, iters=iters)
-        cost = banded_decode_cost(b=b, h_q=h_q, h_kv=h_kv, t=t, d=d,
-                                  window=window, num_global=g, cap=cap)
-        us = max(lat["p50_us"], 1e-9)
-        rows.append({**sh, "impl": impl, **lat, **cost,
-                     "achieved_gflops": cost["flops"] / us / 1e3,
-                     "achieved_gbps": cost["hbm_bytes"] / us / 1e3})
-    return rows

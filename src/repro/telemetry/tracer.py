@@ -2,42 +2,55 @@
 
 A `Tracer` records per-Request lifecycle timestamps (submit -> queued ->
 admitted -> prefill/first token -> decode blocks -> done/degraded),
-decode-block spans, and the unified degradation-event stream — all in
-bounded ring buffers (`collections.deque(maxlen=capacity)`), so a
-sustained-load engine holds O(capacity) trace memory forever
+host spans, decode-block spans, and the unified degradation-event stream
+— all in bounded ring buffers (`collections.deque(maxlen=capacity)`), so
+a sustained-load engine holds O(capacity) trace memory forever
 (test_telemetry.py pins this).
+
+Host spans (`Tracer.span(name, **counts)`) mark the engine's layer
+boundaries (`swat.plan`, `swat.admit`, `swat.decode` and their children).
+Each is a `jax.profiler.TraceAnnotation` — inert unless a profiler runs,
+and then on the profiler's host plane, on the same clock as the device's
+operations — and a record (name, t0, t1, counts) on the tracer's clock.
+Counts are integers the host already holds when the span opens; a span
+formats no strings and touches no device value.
 
 Derived latencies per finished request:
 
   queue_delay  submit -> admission (last attempt's admission for retried
                requests — stats are PER ATTEMPT, a retry restarts the
-               prefill clock but never the submit clock)
+               prefill clock but never the submit clock); None for a
+               request admitted without a submit time
   ttft         submit -> first sampled token of the attempt that
                finalized (time-to-first-token as the CLIENT sees it:
-               tokens from a failed attempt died with its slot)
+               tokens from a failed attempt died with its slot); None
+               without a submit time
   tpot         (finish - first token) / (tokens - 1): steady-state
                time-per-output-token; resolution is one decode block
                (the host-sync quantum — the tracer never adds syncs)
 
 Exports: `chrome_trace()` (load in chrome://tracing / Perfetto) and
 `prometheus_text()` (text exposition, scrape or diff in CI). The clock
-is injectable for deterministic tests.
+is injectable for deterministic tests; the default, `time.perf_counter`,
+is the monotonic clock a harness in the same process reads.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import time
-from typing import Any, Callable, Deque, Dict, List, Optional
+import weakref
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
 class RequestRecord:
     """One finished request's lifecycle timestamps (tracer clock)."""
     rid: int
-    submit: float
+    submit: Optional[float]         # None: admitted without a submit time
     admit: Optional[float]          # None: rejected before admission
     first_token: Optional[float]
     finish: float
@@ -47,12 +60,15 @@ class RequestRecord:
 
     @property
     def queue_delay(self) -> Optional[float]:
-        return None if self.admit is None else self.admit - self.submit
+        if self.admit is None or self.submit is None:
+            return None
+        return self.admit - self.submit
 
     @property
     def ttft(self) -> Optional[float]:
-        return (None if self.first_token is None
-                else self.first_token - self.submit)
+        if self.first_token is None or self.submit is None:
+            return None
+        return self.first_token - self.submit
 
     @property
     def tpot(self) -> Optional[float]:
@@ -61,13 +77,50 @@ class RequestRecord:
         return (self.finish - self.first_token) / (self.tokens - 1)
 
 
+class Span:
+    """Context manager of `Tracer.span`: a profiler annotation while a
+    profiler runs (none otherwise: the check is one call), and a record
+    `(name, t0, t1, counts)` appended to `tracer.spans` when it closes.
+    `t0` and `t1` are readable after entry and exit."""
+    __slots__ = ("tracer", "name", "counts", "t0", "t1", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, counts: Dict[str, int]):
+        self.tracer, self.name, self.counts = tracer, name, counts
+
+    def __enter__(self) -> "Span":
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name, **self.counts)
+            self._ann.__enter__()
+        self.t0 = self.tracer.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = self.tracer.clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.tracer.spans.append((self.name, self.t0, self.t1, self.counts))
+        return False
+
+
+_LIVE: List["weakref.ref[Tracer]"] = []
+
+
+def live_tracers() -> List["Tracer"]:
+    """Every Tracer alive in this process, oldest first: the in-process
+    scrape point for code that reads an engine's spans without holding
+    the engine (a harness driving it through its scheduler and blocks)."""
+    _LIVE[:] = [r for r in _LIVE if r() is not None]
+    return [t for t in (r() for r in _LIVE) if t is not None]
+
+
 class Tracer:
     """Ring-buffered lifecycle tracer. All hooks are O(1) host Python —
     no device work, no syncs; the engine calls them strictly outside the
     transfer-guarded block dispatch."""
 
     def __init__(self, capacity: int = 4096,
-                 clock: Callable[[], float] = time.monotonic):
+                 clock: Callable[[], float] = time.perf_counter):
         assert capacity >= 1
         self.capacity = capacity
         self.clock = clock
@@ -76,25 +129,41 @@ class Tracer:
             maxlen=capacity)
         self.blocks: Deque[Dict[str, Any]] = collections.deque(
             maxlen=capacity)
+        # (name, t0, t1, counts) of each closed host span
+        self.spans: Deque[Tuple[str, float, float, Dict[str, int]]] = \
+            collections.deque(maxlen=capacity)
         self.events: Deque[Dict[str, Any]] = collections.deque(
             maxlen=capacity)
         self._open: Dict[int, Dict[str, Any]] = {}
         self.dropped_requests = 0     # finalize seen without a submit
+        live_tracers()                # drops the dead before registering
+        _LIVE.append(weakref.ref(self))
+
+    def span(self, name: str, **counts: int) -> Span:
+        """A host span over a `with` block: `name` and integer `counts`
+        become the profiler annotation's name and arguments and the
+        record's fields."""
+        return Span(self, name, counts)
 
     # ------------------------------------------------------------ lifecycle
-    def on_submit(self, rid: int) -> None:
-        self._open[rid] = {"submit": self.clock(), "admit": None,
-                           "first_token": None, "attempts": 0}
+    def on_submit(self, rid: int, t: Optional[float] = None) -> None:
+        """A request entered the queue, at `t` on the tracer's clock (now
+        when None): a caller that knows when the request was due passes
+        that time, so queue delay and TTFT count from it."""
+        self._open[rid] = {"submit": self.clock() if t is None else t,
+                           "admit": None, "first_token": None,
+                           "attempts": 0}
 
     def on_admit(self, rids) -> None:
         """One admission batch entered prefill. A rid admitted AGAIN is a
         retry: the attempt counter bumps and the first-token clock resets
-        (per-attempt stats), while submit stays — the client queued once."""
+        (per-attempt stats), while submit stays — the client queued once.
+        A rid admitted without a submit has no queue delay or TTFT."""
         t = self.clock()
         for rid in rids:
             rec = self._open.get(rid)
             if rec is None:
-                rec = self._open[rid] = {"submit": t, "admit": None,
+                rec = self._open[rid] = {"submit": None, "admit": None,
                                          "first_token": None, "attempts": 0}
             rec["admit"] = t
             rec["first_token"] = None
@@ -107,11 +176,13 @@ class Tracer:
             if rec is not None and rec["first_token"] is None:
                 rec["first_token"] = t
 
-    def on_block(self, mode: str, n: int, t0: float, tokens: int) -> None:
-        """One decode block span: t0 from `clock()` before dispatch, the
-        span closes at the host sync draining the block's outputs."""
+    def on_block(self, mode: str, n: int, t0: float, tokens: int,
+                  t1: Optional[float] = None) -> None:
+        """One decode block from t0 to t1 (now when None); the engine
+        passes the bounds of its `swat.decode` span."""
+        t1 = self.clock() if t1 is None else t1
         self.blocks.append({"mode": mode, "n": n, "t0": t0,
-                            "dur": self.clock() - t0, "tokens": tokens})
+                            "dur": t1 - t0, "tokens": tokens})
 
     def on_finish(self, rid: int, status: str, tokens: int) -> None:
         t = self.clock()
@@ -159,7 +230,8 @@ class Tracer:
     def chrome_trace(self, *, metadata: Optional[dict] = None) -> dict:
         """Chrome-trace JSON (chrome://tracing, Perfetto): request phases
         on pid 1 (one tid per rid), decode-block spans on pid 0 tid 0,
-        degradation events as instants on pid 0 tid 1."""
+        degradation events as instants on pid 0 tid 1, host spans on pid 0
+        tid 2."""
         tev: List[dict] = [
             {"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
              "args": {"name": "engine"}},
@@ -171,6 +243,10 @@ class Tracer:
                         "cat": "decode", "ph": "X", "pid": 0, "tid": 0,
                         "ts": self._us(b["t0"]), "dur": b["dur"] * 1e6,
                         "args": {"tokens": b["tokens"]}})
+        for name, t0, t1, counts in self.spans:
+            tev.append({"name": name, "cat": "span", "ph": "X", "pid": 0,
+                        "tid": 2, "ts": self._us(t0), "dur": (t1 - t0) * 1e6,
+                        "args": counts})
         for ev in self.events:
             args = {k: v for k, v in ev.items() if k not in ("ts", "kind")}
             tev.append({"name": ev.get("kind", "event"), "cat": "degrade",
@@ -180,7 +256,8 @@ class Tracer:
             tid = r.rid
             phases = []
             if r.admit is not None:
-                phases.append(("queued", r.submit, r.admit))
+                if r.submit is not None:
+                    phases.append(("queued", r.submit, r.admit))
                 end_first = (r.first_token if r.first_token is not None
                              else r.finish)
                 phases.append(("prefill", r.admit, end_first))

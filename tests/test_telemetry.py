@@ -19,14 +19,20 @@ The observability contract (src/repro/telemetry/ + the engine hooks):
     schema validators,
   * degradation events flow over ONE bus: `faults.record_event` is a
     shim over `telemetry.events`, and engine tracers see the same stream
-    `consume_events()` drains.
+    `consume_events()` drains,
+  * the engine's host spans land in a profiler trace, nested, with their
+    counts; its programs carry their names and named scopes; its layer
+    counters match a hand count.
 
 The 4-device slot-parallel metrics-identity case lives in
 test_serving_sharded.py with the other mesh suites.
 """
+import pathlib
+import re
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -38,7 +44,7 @@ from repro.serving.faults import FaultPlan
 from repro.telemetry import events as TEV
 from repro.telemetry import kernelprof as KP
 from repro.telemetry import metrics as MX
-from repro.telemetry.tracer import Tracer, prometheus_text
+from repro.telemetry.tracer import Tracer, live_tracers, prometheus_text
 from repro.telemetry.validate import (validate_chrome_trace,
                                       validate_prometheus)
 
@@ -299,6 +305,197 @@ def test_tracer_retry_restarts_attempt_clock():
     assert rec.attempts == 2
     assert rec.submit == 0.0 and rec.admit == 3.0
     assert rec.ttft == 5.0
+
+
+def test_on_submit_takes_the_callers_due_time():
+    """A caller that knows when a request was due passes it: queue delay
+    and TTFT count from that time, not from the call."""
+    t = {"now": 5.0}
+    tr = Tracer(capacity=4, clock=lambda: t["now"])
+    tr.on_submit(3, t=2.0)
+    t["now"] = 6.0
+    tr.on_admit([3])
+    t["now"] = 7.5
+    tr.on_first_token([3])
+    tr.on_finish(3, "ok", 1)
+    (rec,) = tr.records
+    assert rec.submit == 2.0
+    assert rec.queue_delay == 4.0
+    assert rec.ttft == 5.5
+
+
+def test_admit_without_submit_has_no_queue_delay():
+    """A request admitted with no submit time has no queue delay or TTFT
+    (not 0), and the latency summary leaves it out."""
+    t = {"now": 0.0}
+    tr = Tracer(capacity=4, clock=lambda: t["now"])
+    tr.on_submit(1)
+    t["now"] = 1.0
+    tr.on_admit([1, 2])               # rid 2 was never submitted
+    t["now"] = 2.0
+    tr.on_first_token([1, 2])
+    tr.on_finish(1, "ok", 1)
+    tr.on_finish(2, "ok", 1)
+    recs = {r.rid: r for r in tr.records}
+    assert recs[1].queue_delay == 1.0 and recs[1].ttft == 2.0
+    assert recs[2].submit is None
+    assert recs[2].queue_delay is None and recs[2].ttft is None
+    summ = tr.latency_summary()
+    assert summ["queue_delay"]["count"] == 1
+    assert summ["queue_delay"]["p50"] == 1.0
+    assert summ["ttft"]["count"] == 1
+    assert validate_chrome_trace(tr.chrome_trace()) == []
+
+
+def test_span_records_on_the_tracers_clock():
+    """A span records (name, t0, t1, counts) on the tracer's clock, nests,
+    and is exported to the Chrome trace; tracers are found alive."""
+    t = {"now": 0.0}
+    tr = Tracer(capacity=8, clock=lambda: t["now"])
+    with tr.span("swat.decode", n=4, slots=2, live=1) as outer:
+        t["now"] = 1.0
+        with tr.span("swat.decode.sync"):
+            t["now"] = 3.0
+        t["now"] = 4.0
+    assert (outer.t0, outer.t1) == (0.0, 4.0)
+    assert list(tr.spans) == [("swat.decode.sync", 1.0, 3.0, {}),
+                              ("swat.decode", 0.0, 4.0,
+                               {"n": 4, "slots": 2, "live": 1})]
+    doc = tr.chrome_trace()
+    assert validate_chrome_trace(doc) == []
+    spans = [ev for ev in doc["traceEvents"] if ev.get("cat") == "span"]
+    assert [ev["name"] for ev in spans] == ["swat.decode.sync", "swat.decode"]
+    assert spans[1]["args"] == {"n": 4, "slots": 2, "live": 1}
+    assert any(x is tr for x in live_tracers())
+
+
+# ------------------------------------------- spans, scopes, counters ----
+
+
+def _prompt(cfg, n, seed):
+    return np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (n,)).astype(np.int32)
+
+
+def _host_events(trace_dir):
+    """swat.* events of the newest xplane under trace_dir, in start order:
+    (name, start_ns, end_ns, args)."""
+    from jax.profiler import ProfileData
+    path = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("swat."):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda ev: (ev[1], -ev[2]))
+
+
+def test_spans_land_in_the_profiler_trace(swat_setup, tmp_path):
+    """A served batch under the profiler yields swat.plan, swat.admit and
+    swat.decode with their children; every child lies inside its parent;
+    padded_tokens is rows x padded length and emitted is what the block
+    delivered."""
+    cfg, params = swat_setup
+    eng = ServingEngine(cfg, params, batch_slots=2, max_len=64,
+                        scan_steps=4)
+    reqs = [Request(rid=i, prompt=_prompt(cfg, n, i), max_new_tokens=6)
+            for i, n in enumerate((5, 20, 12))]
+    eng.run([Request(rid=9, prompt=_prompt(cfg, 8, 9), max_new_tokens=3)])
+    with jax.profiler.trace(str(tmp_path)):
+        out = eng.run(reqs)
+    assert all(r.ok for r in out)
+    evs = _host_events(tmp_path)
+    names = {ev[0] for ev in evs}
+    assert {"swat.plan", "swat.admit", "swat.admit.prefill",
+            "swat.admit.sample", "swat.admit.insert", "swat.decode",
+            "swat.decode.stage", "swat.decode.dispatch", "swat.decode.sync",
+            "swat.decode.retire"} <= names
+    parents = [ev for ev in evs if ev[0] in ("swat.admit", "swat.decode")]
+    for name, s0, e0, _ in evs:
+        if name.count(".") == 2:
+            parent = name.rsplit(".", 1)[0]
+            assert any(p[0] == parent and p[1] <= s0 and e0 <= p[2]
+                       for p in parents), name
+    admits = [ev[3] for ev in evs if ev[0] == "swat.admit"]
+    for a in admits:
+        assert a["padded_tokens"] == a["rows"] * a["padded_len"]
+    # rids 0 and 1 share a batch padded to 32; rid 2 comes alone, to 16
+    assert sorted((a["rows"], a["padded_len"], a["prompt_tokens"])
+                  for a in admits) == [(1, 16, 12), (2, 32, 25)]
+    blocks = [ev for ev in evs if ev[0] == "swat.decode"]
+    emitted = [ev[3]["emitted"] for ev in evs
+               if ev[0] == "swat.decode.retire"]
+    assert len(blocks) == len(emitted) == len(eng.tracer.blocks) - 1
+    assert emitted == [b["tokens"] for b in list(eng.tracer.blocks)[1:]]
+    assert sum(emitted) == sum(len(r.tokens) - 1 for r in out)
+    for _, _, _, a in blocks:
+        assert a["slots"] == 2 and 1 <= a["live"] <= 2 and a["n"] >= 1
+
+
+def _scan_args(eng):
+    dev = eng._dev
+    return (eng.params, eng.caches, dev["tok"], dev["active"],
+            dev["budget"], dev["temps"], dev["anyt"], eng.key,
+            dev["poisoned"])
+
+
+@pytest.mark.parametrize("program", ["decode_scan", "prefill",
+                                     "prefill_chunk"])
+def test_programs_carry_names_and_scopes(swat_setup, program):
+    """Compiled serving programs are named jit_swat_<program> and their
+    operations carry the model's named scopes."""
+    cfg, params = swat_setup
+    eng = ServingEngine(cfg, params, batch_slots=2, max_len=64,
+                        scan_steps=4, prefill_chunk=16)
+    eng.run(mkreqs(cfg, n=2, m=6))
+    tok = jnp.zeros((2, 16), jnp.int32)
+    lens = jnp.full((2,), 16, jnp.int32)
+    if program == "decode_scan":
+        # the staged slot vectors outlive the run's last block
+        lowered = eng._c.scan(4, 2).lower(*_scan_args(eng))
+    elif program == "prefill":
+        lowered = eng._c.prefill(2).lower(eng.params, tok, lens)
+    else:
+        lowered = eng._c.chunk(2).lower(
+            eng.params, eng._c.fresh_caches(2), tok, jnp.int32(0), lens,
+            jnp.zeros((2, cfg.vocab_size), jnp.float32))
+    text = lowered.compile().as_text()
+    assert text.startswith(f"HloModule jit_swat_{program},")
+    scopes = set(re.findall(r'op_name="jit\(swat_\w+\)/([^"]*)"', text))
+    joined = "\n".join(scopes)
+    for want in ("attn/qkv/", "attn/ring_write/", "attn/attend/",
+                 "attn/out/", "mlp/", "embed/", "unembed/"):
+        assert want in joined, (want, sorted(scopes)[:20])
+    if program == "decode_scan":
+        assert "sample/" in joined
+
+
+def test_layer_counters_hand_checked(swat_setup):
+    """engine.stats counts padded prefill tokens and decode blocks, steps
+    and slot-steps. Two slots, blocks of at most 4 steps, three requests
+    of 10 tokens (9 decoded each) with prompts of 5, 20 and 12 tokens
+    padded to multiples of 16: rids 0-1 prefill as 2 x 32, rid 2 as
+    1 x 16; each wave decodes in blocks of 4, 4 and 1 steps."""
+    cfg, params = swat_setup
+    eng = ServingEngine(cfg, params, batch_slots=2, max_len=64,
+                        scan_steps=4, pad_to=16)
+    reqs = [Request(rid=i, prompt=_prompt(cfg, n, i), max_new_tokens=10)
+            for i, n in enumerate((5, 20, 12))]
+    assert all(r.ok for r in eng.run(reqs))
+    st = eng.stats
+    assert st["prefill_tokens_computed"] == 5 + 20 + 12
+    assert st["prefill_tokens_padded"] == 2 * 32 + 16
+    assert st["decode_blocks"] == 6
+    assert st["decode_steps"] == 2 * (4 + 4 + 1)
+    assert st["decode_slot_steps"] == 2 * st["decode_steps"]
+    assert st["tokens_emitted"] == 3 * 9
+    text = eng.metrics_text()
+    assert validate_prometheus(text) == []
+    for k in ("prefill_tokens_padded", "decode_blocks", "decode_steps",
+              "decode_slot_steps"):
+        assert f"swat_{k} {st[k]}" in text
 
 
 # ------------------------------------------------- exports + schema ----
