@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.types import AttentionSpec, ModelConfig
 from repro.kernels import ops as kops
+from repro.kernels.ref import put_layer, take_layer
 
 Params = Dict[str, Any]
 
@@ -229,8 +230,8 @@ def init_kv_cache(cfg: AttentionLayerCfg, batch: int, max_len: int,
 # view width equals the contiguous allocation exactly, which is why
 # PAGE_SIZE divides every `cache_allocation`), and scatters the whole view
 # back. Bitwise identity with the contiguous engine falls out by
-# construction; the cost is a pool-sized copy per step, the same O(cache)
-# traffic the `_dyn_update` select already pays.
+# construction; the cost is a pool-sized copy per step, which the
+# contiguous ring, written a row at a time in place, does not pay.
 
 PAGE_SIZE = 16   # rows per block == the bf16 sublane tile `_round_capacity`
                  # rounds to, so every ring allocation is block-divisible
@@ -311,14 +312,24 @@ def paged_scatter(pool, table, view):
 
 
 def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
-                     impl: str = "ref", lookahead: int = 0, mesh=None):
-    """T-token decode. x: (B, T, Dm). Ring insertion at (step mod cap) for
-    sparse specs — the paper's FIFO replacement policy (row index mod window)
-    — happens INSIDE the attention call (`decode_attention(new_kv=)`): the
-    pallas impl writes the new rows into the cache block it already holds in
-    VMEM (input-stationary, Fig. 4b) instead of paying a separate scatter
-    dispatch and full-cache HBM round trip per layer per token; the ref impl
-    scatters-then-attends with identical masks (the parity oracle). Global
+                     layer, impl: str = "ref", lookahead: int = 0,
+                     mesh=None):
+    """T-token decode of one layer of a layer scan. x: (B, T, Dm). cache:
+    this layer kind's caches stacked over the scan (leading axis N, the
+    scan's carry), of which layer `layer` (traced int) decodes; they come
+    back stacked with the layer's rows written and its `step` advanced,
+    every other leaf (xattn's `xk`/`xv`) untouched.
+
+    Ring insertion at (step mod cap) for sparse specs — the paper's FIFO
+    replacement policy (row index mod window) — happens INSIDE the
+    attention call (`decode_attention(new_kv=)`): the pallas impl writes
+    the new rows into the cache block it already holds in VMEM
+    (input-stationary, Fig. 4b); the ref impl writes them, then attends
+    with identical masks (the parity oracle). Where
+    `kops.ring_rows_in_place` holds (the ref impl on contiguous rings) the
+    write goes straight into the stack, rows only; the pallas kernel and a
+    paged pool's contiguous view take the layer's ring out of the stack
+    and put it back. Global
     tokens occupy pinned slots [0, g) (paper §4.1's fixed K/V buffers); the
     ring occupies [g, cap). `step` is per-slot (B,): every row rotates,
     ropes, and masks at its own depth, which is what lets one batched call
@@ -331,7 +342,8 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
     write-back too), `attend` (a paged cache's gather too), `out`. The
     pallas kernel inserts inside its attention pass, under `attend`."""
     b, t, _ = x.shape
-    step = jnp.broadcast_to(jnp.asarray(cache["step"], jnp.int32), (b,))
+    step = take_layer(cache["step"], layer).astype(jnp.int32)
+    step = jnp.broadcast_to(step, (b,))
     with jax.named_scope("qkv"):
         q, k_new, v_new = _project_qkv(params, cfg, x, x)
         if cfg.use_rope and not cfg.cross:
@@ -340,18 +352,24 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
             q = apply_rope(q, pos, cfg.rope_theta)
             k_new = apply_rope(k_new, pos, cfg.rope_theta)
     paged = "table" in cache
+    in_place = kops.ring_rows_in_place(impl, paged)
     if paged:
         # contiguous view of the block table; width == the contiguous
         # allocation, so everything below is bit-for-bit the ring path
+        pk, pv, table = (take_layer(cache[n], layer)
+                         for n in ("pk", "pv", "table"))
         with jax.named_scope("attend"):
-            k_arr = paged_gather(cache["pk"], cache["table"])
-            v_arr = paged_gather(cache["pv"], cache["table"])
-    else:
+            k_arr = paged_gather(pk, table)
+            v_arr = paged_gather(pv, table)
+    elif in_place:
         k_arr, v_arr = cache["k"], cache["v"]
+    else:
+        k_arr = take_layer(cache["k"], layer)
+        v_arr = take_layer(cache["v"], layer)
     # rotate and mask at the LOGICAL capacity: the allocation may carry a
     # tile-rounding tail of zero rows that must never be written or attended
     # (otherwise the rounding would silently widen the attention window)
-    cap = cache_capacity(cfg, k_arr.shape[2], lookahead)
+    cap = cache_capacity(cfg, k_arr.shape[-2], lookahead)
     g = cfg.spec.num_global if cfg.spec.is_sparse else 0
     assert t == 1 or not cfg.spec.is_sparse \
         or cap - g >= cfg.spec.window + t, (
@@ -359,17 +377,20 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
             "tokens: allocate caches with lookahead >= T-1")
     out, k_cache, v_cache = kops.decode_attention(
         q, k_arr, v_arr, None, cfg.spec, impl=impl,
-        new_kv=(k_new, v_new), pos=step, ring_cap=cap, mesh=mesh)
+        new_kv=(k_new, v_new), pos=step, ring_cap=cap, mesh=mesh,
+        layer=layer if in_place else None)
+    new_cache = {**cache, "step": put_layer(cache["step"], layer, step + t)}
     if paged:
         with jax.named_scope("ring_write"):
-            new_cache = {**cache,
-                         "pk": paged_scatter(cache["pk"], cache["table"],
-                                             k_cache),
-                         "pv": paged_scatter(cache["pv"], cache["table"],
-                                             v_cache),
-                         "step": step + t}
+            new_cache["pk"] = put_layer(cache["pk"], layer,
+                                        paged_scatter(pk, table, k_cache))
+            new_cache["pv"] = put_layer(cache["pv"], layer,
+                                        paged_scatter(pv, table, v_cache))
+    elif in_place:
+        new_cache.update(k=k_cache, v=v_cache)
     else:
-        new_cache = {"k": k_cache, "v": v_cache, "step": step + t}
+        new_cache.update(k=put_layer(cache["k"], layer, k_cache),
+                         v=put_layer(cache["v"], layer, v_cache))
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
         return out @ params["wo"], new_cache
@@ -383,9 +404,11 @@ def _dyn_update(cache, new, slot):
     a dynamic index across a sequence-sharded cache forces XLA SPMD into
     "involuntary full rematerialization" (it replicates the whole cache
     every step). The select partitions trivially under any cap sharding at
-    the cost of a full-cache write — decode already reads the full cache for
-    attention, so the added traffic is bounded at ~1.5x and the collective
-    catastrophe is gone (see EXPERIMENTS.md §Perf).
+    the cost of a full-cache read and write. Decode does not insert this
+    way: it scatters the step's rows (`ref.ring_insert_ref`, bitwise this
+    function's ring at T=1) per shard of the ring, rows split or not
+    (`ops._shard_row_write`), which leaves SPMD no dynamic write to
+    partition.
     cache: (B, H, cap, D); new: (B, H, 1, D); slot: (B,) or scalar int32."""
     b, _, cap, _ = cache.shape
     slot = jnp.broadcast_to(jnp.asarray(slot, jnp.int32), (b,))

@@ -34,6 +34,7 @@ from repro.core import moe as M
 from repro.core import ssm as S
 from repro.core.types import AttentionSpec, ModelConfig
 from repro.kernels import ops as kops
+from repro.kernels.ref import put_layer, take_layer
 
 Params = Dict[str, Any]
 
@@ -383,33 +384,39 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int,
     return jax.vmap(one)(jnp.arange(cfg.num_super_blocks))
 
 
-def _apply_layer_decode(p, cfg, kind, x, cache, *, enc_out=None,
+def _apply_layer_decode(p, cfg, kind, x, cache, *, layer, enc_out=None,
                         impl: str = "ref", lookahead: int = 0,
                         index: Optional[int] = None, mesh=None):
+    """One layer of `decode_step`'s scan. cache: this pattern position's
+    caches stacked over the scan (its carry); layer `layer` is read out of
+    it and only what the step changes is written back."""
     mamba = kind.startswith("mamba")
     with jax.named_scope("mamba" if mamba else "attn"):
         h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
         if mamba:
-            y, new_cache = S.mamba_decode(p["mixer"], h, cache, cfg.ssm)
+            y, state = S.mamba_decode(
+                p["mixer"], h,
+                jax.tree.map(lambda c: take_layer(c, layer), cache), cfg.ssm)
+            new_cache = jax.tree.map(lambda c, n: put_layer(c, layer, n),
+                                     cache, state)
         else:
             y, new_cache = L.attention_decode(
                 p["mixer"], attn_cfg(cfg, kind, index=index), h, cache,
-                impl=impl, lookahead=lookahead, mesh=mesh)
+                layer=layer, impl=impl, lookahead=lookahead, mesh=mesh)
         x = x + y
     if kind == "xattn":
         with jax.named_scope("xattn"):
             h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
             ccfg = attn_cfg(cfg, kind, cross=True)
             q, _, _ = L._project_qkv(p["cross"], ccfg, h, h)
+            xk = take_layer(cache["xk"], layer)
             out = kops.decode_attention(
-                q, cache["xk"], cache["xv"],
-                jnp.full((x.shape[0], 1, 1, 1), cache["xk"].shape[2],
-                         jnp.int32),
+                q, xk, take_layer(cache["xv"], layer),
+                jnp.full((x.shape[0], 1, 1, 1), xk.shape[2], jnp.int32),
                 ccfg.spec)
             out = out.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1],
                                                     -1)
             x = x + out @ p["cross"]["wo"]
-        new_cache = {**new_cache, "xk": cache["xk"], "xv": cache["xv"]}
     return _ffn_serving(p, cfg, x), new_cache
 
 
@@ -429,7 +436,16 @@ def decode_step(params: Params, cfg: ModelConfig, batch, caches, *,
     every super-block boundary — under a serving mesh this keeps the decode
     residual stream slot-sharded instead of letting SPMD replicate it
     between blocks. mesh: the serving mesh the caller's jit is partitioned
-    over — the pallas kernel then runs per shard (`ops.decode_attention`).
+    over — the pallas kernel and the ref impl's row write then run per
+    shard (`ops.decode_attention`).
+
+    The layer scan carries the stacked caches: each layer reads its own
+    out of the carry and writes back only what it changed. The ref impl
+    writes a contiguous ring's new rows straight into the carry
+    (`ops.ring_rows_in_place`), so a step moves its rows, not its rings;
+    the pallas kernel and paged pools write the layer's whole ring.
+    xattn's encoder memory is never written, mamba states and ring `step`s
+    at their layer index.
     Returns (logits (B, T, V), new caches)."""
     x = embed_tokens(params, cfg, batch)
     assert x.shape[1] == 1 or all(
@@ -437,21 +453,21 @@ def decode_step(params: Params, cfg: ModelConfig, batch, caches, *,
         "multi-token decode: mamba layers update state one token at a time"
     dec_impl = "pallas" if impl == "pallas" else "ref"
 
-    def block_fn(x, inp):
-        blk_p, blk_cache = inp
-        new_caches = {}
+    def block_fn(carry, inp):
+        x, caches = carry
+        blk_p, layer = inp
+        caches = dict(caches)
         for i, kind in enumerate(cfg.layer_pattern):
-            x, nc = _apply_layer_decode(blk_p[f"l{i}"], cfg, kind, x,
-                                        blk_cache[f"l{i}"], impl=dec_impl,
-                                        lookahead=lookahead, index=i,
-                                        mesh=mesh)
-            new_caches[f"l{i}"] = nc
-        return L.with_activation_constraint(x, act_sharding), new_caches
+            x, caches[f"l{i}"] = _apply_layer_decode(
+                blk_p[f"l{i}"], cfg, kind, x, caches[f"l{i}"], layer=layer,
+                impl=dec_impl, lookahead=lookahead, index=i, mesh=mesh)
+        return (L.with_activation_constraint(x, act_sharding), caches), None
 
-    x, new_caches = jax.lax.scan(
-        block_fn, x, (params["blocks"], caches),
-        unroll=cfg.num_super_blocks if unroll else 1)
-    return _unembed(params, cfg, x), new_caches
+    n = cfg.num_super_blocks
+    (x, caches), _ = jax.lax.scan(
+        block_fn, (x, caches), (params["blocks"], jnp.arange(n)),
+        unroll=n if unroll else 1)
+    return _unembed(params, cfg, x), caches
 
 
 def prefill(params: Params, cfg: ModelConfig, batch, max_len: int, *,

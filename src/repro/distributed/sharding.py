@@ -267,5 +267,12 @@ def decode_kernel_spec(shape, mesh: Mesh) -> P:
     return _spec_for(tuple(shape), _CACHE_RULES[0][1][:2], mesh)
 
 
+def ring_spec(shape, mesh: Mesh) -> P:
+    """Spec of a (B, H, W, D) K/V ring as the cache rule places it: slots,
+    then kv heads, then, where kv heads or slots do not divide their axes,
+    the ring's rows W."""
+    return _spec_for(tuple(shape), _CACHE_RULES[0][1], mesh)
+
+
 def replicated(mesh: Mesh):
     return NamedSharding(mesh, P())

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.core import patterns
 from repro.core.types import AttentionSpec
+from repro.distributed.sharding import decode_kernel_spec, ring_spec
 from repro.kernels import dots
 from repro.kernels import ref as ref_impl
 from repro.kernels import swat_attention as fwd_mod
@@ -57,7 +58,7 @@ def _census_dispatch(q, k_cache, *, impl: str, fused: bool, cap: int,
         return
     b, hq, t, d = q.shape
     KP.record_dispatch(op="decode_attention", impl=impl, fused=fused,
-                       b=b, h_q=hq, h_kv=k_cache.shape[1], t=t, d=d,
+                       b=b, h_q=hq, h_kv=k_cache.shape[-3], t=t, d=d,
                        cap=cap, num_global=g, window=window)
 
 
@@ -310,7 +311,6 @@ def _shard_decode_kernel(call, mesh, cache_shape, fuse: bool):
     follow the cache (a query-head block maps onto its KV-head block)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import decode_kernel_spec
     kv = decode_kernel_spec(cache_shape, mesh)     # all four dims named
     vec = P(kv[0])
     in_specs = (kv, kv, kv, vec) + ((kv, kv, vec) if fuse else ())
@@ -319,11 +319,46 @@ def _shard_decode_kernel(call, mesh, cache_shape, fuse: bool):
                          check_vma=False)
 
 
+def _shard_row_write(write, mesh, cache_shape):
+    """The row write per shard (`jax.shard_map`), as the cache sharding
+    places slots, kv heads and ring rows (`sharding.ring_spec`): each shard
+    writes the rows that fall in its part of the ring and drops the rest.
+    Left to SPMD, the write finds the slot axis parallel only from an iota
+    it can see, which XLA folds away when a slot has a single row to
+    write, and then gathers every slot's new rows to every device."""
+    from jax.sharding import PartitionSpec as P
+
+    lead = len(cache_shape) - 4
+    ring = ring_spec(cache_shape[lead:], mesh)
+    cache = P(*((None,) * lead + tuple(ring)))
+    rows_axes = ring[2]
+
+    def local(c, n, p, nn, layer):
+        row0 = (0 if rows_axes is None
+                else jax.lax.axis_index(rows_axes) * c.shape[-2])
+        return write(c, n, p, nn, layer, row0)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(cache, P(ring[0], ring[1]), P(ring[0]),
+                                   P(ring[0]), P()),
+                         out_specs=cache, check_vma=False)
+
+
+def ring_rows_in_place(impl: str, paged: bool) -> bool:
+    """Whether a decode step writes its K/V rows straight into the layer
+    scan's stacked rings (`decode_attention(layer=)`, the ref impl on
+    contiguous rings), touching no other row. The pallas kernel inserts
+    into the layer's ring inside its pass, and a paged pool is gathered
+    into a contiguous ring and scattered back whole: both take the ring
+    out of the stack and put it back."""
+    return impl != "pallas" and not paged
+
+
 def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
                      scale: Optional[float] = None, impl: str = "ref",
                      interpret: Optional[bool] = None,
                      new_kv=None, num_new=None, pos=None,
-                     ring_cap: Optional[int] = None, mesh=None):
+                     ring_cap: Optional[int] = None, mesh=None,
+                     layer=None):
     """Decode T >= 1 tokens vs a (ring) KV cache. q: (B, Hq, T, D).
     cache_len / pos / num_new are per-slot (scalar, (B,) or (B,1,1,1)): a
     continuously-batched engine serves slots at different ring depths from
@@ -335,11 +370,17 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
       rows are inserted at their ring slots AND attended in the same pass —
       on the pallas impl inside the kernel's VMEM-resident block (no
       separate scatter dispatch, no second full-cache HBM round trip);
-      the ref impl scatters then attends (the unfused parity oracle —
-      identical masks, op-for-op the engine's pre-fusion jnp path).
-      `pos` (required) counts tokens BEFORE the insert; `num_new` optionally
-      ragged-limits how many of the T rows are real per slot (speculative
-      accepts). Returns (out, k_cache, v_cache).
+      the ref impl inserts then attends (the unfused parity oracle —
+      identical masks). `pos` (required) counts tokens BEFORE the insert;
+      `num_new` optionally ragged-limits how many of the T rows are real
+      per slot (speculative accepts). Returns (out, k_cache, v_cache).
+
+    The ref impl inserts with `ref.ring_insert_ref`, one scatter of the
+    step's rows, under a mesh per shard (`_shard_row_write`).
+    layer: None, or (ref impl, fused only) the index (traced int ok) of
+    the layer to decode when k_cache / v_cache are a layer scan's stacked
+    caches (N, B, Hkv, W, D); the caches returned are then the stacks,
+    with the rows written in place (`ring_rows_in_place`).
 
     Masks are positional: `ring_cap` is the LOGICAL rotation modulus
     (defaults to the cache width), spec.num_global the pinned prefix, and
@@ -357,7 +398,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
     attention `attend`; the pallas kernel, which inserts inside its
     attention pass, is `attend`."""
     b, _, t, _ = q.shape
-    w_phys = k_cache.shape[2]
+    w_phys = k_cache.shape[-2]
     cap = w_phys if ring_cap is None else int(ring_cap)
     g = spec.num_global if spec.is_sparse else 0
     window = spec.window if spec.is_sparse else 0
@@ -412,15 +453,23 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
             return call(*args)
     if fuse:
         k_new, v_new = new_kv
+
+        def write(c, n, p, nn, lyr, row0=0):
+            return ref_impl.ring_insert_ref(c, n, p, nn, ring_cap=cap,
+                                            num_global=g, layer=lyr,
+                                            row0=row0)
+        if mesh is not None and mesh.size > 1:
+            write = _shard_row_write(write, mesh, k_cache.shape)
+        lyr = 0 if layer is None else layer
         with jax.named_scope("ring_write"):
-            k_cache = ref_impl.ring_insert_ref(k_cache, k_new, pos, nn,
-                                               ring_cap=cap, num_global=g)
-            v_cache = ref_impl.ring_insert_ref(v_cache, v_new, pos, nn,
-                                               ring_cap=cap, num_global=g)
+            k_cache = write(k_cache, k_new, pos, nn, lyr)
+            v_cache = write(v_cache, v_new, pos, nn, lyr)
+        kc, vc = k_cache, v_cache
+        if layer is not None:
+            kc, vc = ref_impl.take_layer(kc, lyr), ref_impl.take_layer(vc, lyr)
         with jax.named_scope("attend"):
-            out = ref_impl.decode_ref(q, k_cache, v_cache, None, spec,
-                                      scale=scale, total=pos + nn, q0=pos,
-                                      ring_cap=cap)
+            out = ref_impl.decode_ref(q, kc, vc, None, spec, scale=scale,
+                                      total=pos + nn, q0=pos, ring_cap=cap)
         return out, k_cache, v_cache
     if t > 1 or (spec.is_sparse and spec.window
                  and cap > spec.window + 1 + g):

@@ -167,25 +167,58 @@ def ring_slot_positions(total, wcap: int, *, ring_cap: int, num_global: int):
     return t_s, valid & (s_idx < ring_cap)
 
 
+def take_layer(stack, layer):
+    """Layer `layer` (traced int ok) of a layer scan's stacked leaf."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def put_layer(stack, layer, x):
+    """`stack` with layer `layer` replaced by `x`."""
+    return jax.lax.dynamic_update_index_in_dim(
+        stack, x.astype(stack.dtype), layer, 0)
+
+
 def ring_insert_ref(cache, new, pos, num_new, *, ring_cap: int,
-                    num_global: int):
+                    num_global: int, layer=None, row0=0):
     """Insert `new` (B, H, T, D) rows at their ring slots of `cache`
-    (B, H, W, D): token pos+j -> slot g + (pos+j-g) mod ring (pinned below
-    g); rows j >= num_new[b] are not written. Implemented as iota==slot
-    selects (ascending j: last writer wins), the SPMD-safe form
-    layers._dyn_update uses — for T=1 this is op-for-op that function."""
-    b, _, wcap, _ = cache.shape
-    t = new.shape[2]
+    (B, H, W, D), or of layer `layer` (traced int ok) of a layer scan's
+    stack (N, B, H, W, D): token pos+j -> slot g + (pos+j-g) mod ring
+    (pinned below g); rows j >= num_new[b] are dropped, and the
+    tile-rounding tail [ring_cap, W) is never addressed. row0: the ring
+    row that `cache`'s row 0 holds, where `cache` is one shard of a ring
+    split along W; rows that fall outside the shard are dropped.
+
+    One scatter of the step's rows, which reads and writes no other row:
+    on a buffer XLA may update in place (a scan carry, a donated argument)
+    a step costs its rows alone. The ring is bitwise the one iota==slot
+    selects give (layers._dyn_update at T=1; ascending j, last writer
+    wins), which read and write every row. A step's T slots are distinct
+    (the ring holds at least T rows), so write order does not matter.
+    Each update is one D-row, so the scatter's operand keeps its layout: a
+    slot batching dimension, or (H, D) windows, would have XLA move the
+    scatter dimensions to the front of the operand, a transpose of the
+    whole ring."""
+    lead = cache.ndim - 4
+    b, h, t, _ = new.shape
     g, ring = num_global, ring_cap - num_global
     pos = jnp.asarray(pos, jnp.int32).reshape(b)
     num_new = jnp.asarray(num_new, jnp.int32).reshape(b)
-    for j in range(t):
-        pj = pos + j
-        slot = jnp.where(pj < g, pj, g + jnp.mod(pj - g, ring))
-        hit = ((jnp.arange(wcap, dtype=jnp.int32)[None, :] == slot[:, None])
-               & (j < num_new)[:, None])[:, None, :, None]
-        cache = jnp.where(hit, new[:, :, j:j + 1].astype(cache.dtype), cache)
-    return cache
+    pj = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]   # (B, T)
+    slot = jnp.where(pj < g, pj, g + jnp.mod(pj - g, ring))
+    slot = jnp.where(jnp.arange(t)[None, :] < num_new[:, None], slot, -1)
+    shape = (b, h, t)                   # one index per row: [layer,] B, H, W
+    cols = [jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1),
+            jnp.broadcast_to(slot[:, None, :] - row0, shape)]
+    if lead:
+        cols.insert(0, jnp.broadcast_to(jnp.asarray(layer, jnp.int32), shape))
+    dims = tuple(range(lead + 3))
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(3,), inserted_window_dims=dims,
+        scatter_dims_to_operand_dims=dims)
+    return jax.lax.scatter(cache, jnp.stack(cols, -1),
+                           new.astype(cache.dtype), dnums,
+                           mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
 
 
 def decode_ref(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,

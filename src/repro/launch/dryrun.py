@@ -139,7 +139,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
     else:  # decode
         c_specs = Sp.cache_specs(cfg, shape)
         c_shard = Sh.cache_sharding(c_specs, mesh)
-        step = St.make_serve_step(cfg, impl=impl, unroll=unroll)
+        step = St.make_serve_step(cfg, impl=impl, unroll=unroll, mesh=mesh)
         fn = jax.jit(step, in_shardings=(p_shard, c_shard, b_shard),
                      out_shardings=(None, c_shard), donate_argnums=(1,))
         lowered = fn.lower(p_specs, c_specs, batch_specs)

@@ -49,10 +49,12 @@ def make_prefill_step(cfg: ModelConfig, max_len: int, *,
 
 
 def make_serve_step(cfg: ModelConfig, *, impl: str = "xla",
-                    unroll: bool = False) -> Callable:
+                    unroll: bool = False, mesh=None) -> Callable:
+    """mesh: the mesh the caches are sharded over; the pallas kernel and
+    the ref impl's row write run per shard of it (`ops.decode_attention`)."""
     def serve_step(params, caches, batch):
         return Mod.decode_step(params, cfg, batch, caches, impl=impl,
-                               unroll=unroll)
+                               unroll=unroll, mesh=mesh)
     return serve_step
 
 

@@ -58,6 +58,7 @@ jax.config.update("jax_threefry_partitionable", True)
 
 from repro.core import model as Mod
 from repro.core.types import ModelConfig
+from repro.kernels import ops as kops
 from repro.serving import faults as F
 from repro.serving import sampling
 from repro.serving.drafter import NGramDrafter, get_drafter
@@ -224,6 +225,15 @@ class _Compiled:
                                           lookahead=self.lookahead,
                                           shared_pool=self.mesh is None))
         return self._Sh.cache_sharding(shapes, self.mesh)
+
+    @functools.cached_property
+    def row_write(self) -> bool:
+        """Whether the decode programs write each step's K/V rows into the
+        slot caches in place: some layer attends over a K/V ring and
+        `kops.ring_rows_in_place`, the rule the decode step follows."""
+        return (any(not kind.startswith("mamba")
+                    for kind in self.cfg.layer_pattern)
+                and kops.ring_rows_in_place(self.decode_impl, self.paged))
 
     def batch_sharding(self, shapes, n: int, slot_dim: int = 0):
         """decode_batch_sharding over a pytree of ShapeDtypeStructs."""
@@ -1099,7 +1109,9 @@ class ServingEngine:
         # prefill_tokens_padded the tokens the device computed for them
         # (rows x padded length); decode_steps counts the steps decode
         # blocks ran, decode_slot_steps those steps times the slot count
-        # (every slot computes every step, live or not).
+        # (every slot computes every step, live or not), and
+        # decode_steps_row_write the steps whose program wrote their K/V
+        # rows into the rings in place (`_Compiled.row_write`).
         self.stats = {"spec_steps": 0, "draft_proposed": 0,
                       "draft_accepted": 0, "tokens_emitted": 0,
                       "tokens_delivered": 0,
@@ -1110,7 +1122,7 @@ class ServingEngine:
                       "prefill_tokens_padded": 0,
                       "prefill_prefix_shared": 0, "cow_moves": 0,
                       "decode_blocks": 0, "decode_steps": 0,
-                      "decode_slot_steps": 0}
+                      "decode_slot_steps": 0, "decode_steps_row_write": 0}
 
     @property
     def acceptance_rate(self) -> float:
@@ -1561,7 +1573,8 @@ class ServingEngine:
         finished slots. Speculative engines run n draft/verify/accept
         steps instead, each emitting 1..speculative+1 tokens per slot.
 
-        Host spans: `swat.decode` (n, slots, live) around the block, with
+        Host spans: `swat.decode` (n, slots, live, row_write: 1 if the
+        program writes K/V rows in place) around the block, with
         children `stage` (host work before dispatch: restaging the slot
         vectors and placing them), `dispatch` (the scan call until it
         returns), `sync` (the wait for its tokens) and `retire`
@@ -1576,8 +1589,8 @@ class ServingEngine:
         if not live:
             return []
         span = self.tracer.span
-        with span("swat.decode", n=n, slots=self.slots,
-                  live=len(live)) as blk:
+        with span("swat.decode", n=n, slots=self.slots, live=len(live),
+                  row_write=int(self._c.row_write)) as blk:
             with span("swat.decode.stage"):
                 use_spec, probe, inject = self._stage_block(n, live)
             dev = self._dev
@@ -1720,6 +1733,8 @@ class ServingEngine:
         self.stats["decode_blocks"] += 1
         self.stats["decode_steps"] += steps
         self.stats["decode_slot_steps"] += steps * self.slots
+        if self._c.row_write:
+            self.stats["decode_steps_row_write"] += steps
         if self._paged is not None:
             # advance the per-slot ring-write position mirror: sequential
             # steps write one row per executed step unconditionally (+n);
