@@ -8,18 +8,25 @@ token's reference logit lies below the reference's best logit is read; the
 number compared is the widest such gap over the sample. Every request is
 greedy, so an exact engine reads 0 and rounding reads small gaps.
 
+The reference is the configuration's architecture module's `score`
+(arch/__init__.py). Positions where the reference itself is undecided are
+left out: where, at some expert layer, its k-th and (k+1)-th router logits
+lie within the checks file's `router_margin`, a rounding flips the experts
+chosen and the token with them (every position counts where the file sets
+no margin or the model has no experts).
+
 The control (`control=True`) is the same reference in fp8 put in the
-engine's place (reference.py): at each of the same positions, the token
-the control ranks first is read against the float32 reference the same
-way, and its widest gap is reported beside the engine's.
+engine's place: at each of the same decisive positions, the token the
+control ranks first is read against the float32 reference the same way,
+and its widest gap is reported beside the engine's.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-import reference
+import arch
 
 
 def sample(results: Dict[int, Tuple[str, List[int]]], rng,
@@ -44,23 +51,26 @@ def sample(results: Dict[int, Tuple[str, List[int]]], rng,
 
 def compare(weights, cfg: dict, prompts: Dict[int, np.ndarray],
             results: Dict[int, Tuple[str, List[int]]], rids: List[int],
-            control: bool = False) -> dict:
-    widest, ctrl, n, spread = 0.0, 0.0, 0, []
+            control: bool = False, router_margin: Optional[float] = None) -> dict:
+    score = arch.load(cfg).score
+    widest, ctrl, n, left, spread = 0.0, 0.0, 0, 0, []
     for rid in rids:
         prompt, served = prompts[rid], np.asarray(results[rid][1], np.int32)
         seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
         pos = np.arange(len(prompt) - 1, len(seq), dtype=np.int32)
-        gap, _, sd = reference.score(weights, cfg, seq, pos, served)
-        widest = max(widest, float(gap.max()))
+        gap, _, sd, dec = score(weights, cfg, seq, pos, served,
+                                router_margin=router_margin)
+        if dec.any():
+            widest = max(widest, float(gap[dec].max()))
         spread.append(float(np.median(sd)))
-        n += len(served)
-        if control:
-            _, first, _ = reference.score(weights, cfg, seq, pos, served,
-                                          fp8=True)
-            cgap, _, _ = reference.score(weights, cfg, seq, pos, first)
-            ctrl = max(ctrl, float(cgap.max()))
+        n += int(dec.sum())
+        left += int((~dec).sum())
+        if control and dec.any():
+            _, first, _, _ = score(weights, cfg, seq, pos, served, fp8=True)
+            cgap = score(weights, cfg, seq, pos, first)[0]
+            ctrl = max(ctrl, float(cgap[dec].max()))
     out = {"widest_logit_gap": widest, "tokens_compared": n,
-           "requests_compared": len(rids),
+           "tokens_left_out": left, "requests_compared": len(rids),
            "logit_spread": float(np.median(spread)) if spread else 0.0}
     if control:
         out["control_widest_logit_gap"] = ctrl

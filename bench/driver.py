@@ -1,5 +1,6 @@
-"""The engine adapter: the only file of the benchmark that touches the
-program (`repro`, under the checkout's `src/`).
+"""The engine adapter: with the architecture modules' `program`, the only
+code of the benchmark that touches the program (`repro`, under the
+checkout's `src/`).
 
 It drives `repro.serving.engine.ServingEngine` with the body of its own
 `run()` loop -- `_expire_deadlines`, the `_admit` loop (one scheduler plan
@@ -13,9 +14,11 @@ Each admission and decode block, and each wait for the next arrival, is
 wrapped in a `jax.profiler.TraceAnnotation` (`bench.admit`,
 `bench.decode_block`, `bench.idle_wait`): the layer spans of a traced run.
 
-A configuration passes only what a deployment sizes (`serving`: slots,
-max_len, pad_to, max_prefill_tokens); every implementation choice of the
-engine stays at its default.
+The program's config and parameter tree come from the configuration's
+architecture module (`program`, arch/__init__.py). A configuration passes
+only what a deployment sizes (`serving`: slots, max_len, pad_to,
+max_prefill_tokens); every implementation choice of the engine stays at its
+default.
 """
 from __future__ import annotations
 
@@ -33,82 +36,11 @@ if str(ROOT / "src") not in sys.path:
 import jax                                   # noqa: E402
 import numpy as np                           # noqa: E402
 
-from repro.configs import get_config, with_swat                  # noqa: E402
-from repro.core import model as Mod                              # noqa: E402
-from repro.core.types import MoESpec                             # noqa: E402
+import arch                                 # noqa: E402
 from repro.serving.engine import Request, ServingEngine           # noqa: E402
 
 SERVING_KEYS = ("batch_slots", "max_len", "pad_to", "max_prefill_tokens")
 ANNOTATE = jax.profiler.TraceAnnotation
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file: the registered
-    architecture with the file's sizes, converted to window-plus-sink
-    attention as the file's `swat` group states."""
-    if cfg.get("attention_bias") or cfg.get("mlp_bias"):
-        raise ValueError("the program has no bias terms")
-    base = get_config(cfg["repro_arch"])
-    e = cfg.get("num_local_experts", 0)
-    mc = dataclasses.replace(
-        base,
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        moe=(MoESpec(num_experts=e, top_k=cfg["num_experts_per_tok"],
-                     dispatch=base.moe.dispatch) if e else MoESpec()),
-        rope_theta=float(cfg["rope_theta"]), norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg.get("torch_dtype", "bfloat16"))
-    return with_swat(mc, window=cfg["swat"]["window"],
-                     num_global=cfg["swat"]["num_global"])
-
-
-def registry_departures(cfg: dict) -> List[str]:
-    """Where the program's registered config differs from the file, other
-    than the keys the file lists as reduced."""
-    base = get_config(cfg["repro_arch"])
-    pairs = {"num_hidden_layers": base.num_layers, "hidden_size": base.d_model,
-             "num_attention_heads": base.num_heads,
-             "num_key_value_heads": base.num_kv_heads,
-             "head_dim": base.resolved_head_dim,
-             "intermediate_size": base.d_ff, "vocab_size": base.vocab_size,
-             "num_local_experts": base.moe.num_experts,
-             "num_experts_per_tok": base.moe.top_k,
-             "rope_theta": base.rope_theta, "rms_norm_eps": base.norm_eps,
-             "tie_word_embeddings": base.tie_embeddings}
-    out = []
-    for k, v in pairs.items():
-        want = cfg.get(k, 0)
-        if k not in cfg.get("reduced", []) and v != want:
-            out.append(f"{k}: program {v} file {want}")
-    return out
-
-
-def to_params(weights: dict, mcfg):
-    """The benchmark's plain weight layout as the program's parameter tree
-    (same arrays, no copy), checked leaf by leaf against `init_model`."""
-    lw = weights["layers"]
-    layer = {"norm1": {"scale": lw["attn_norm"]},
-             "mixer": {k: lw[k] for k in ("wq", "wk", "wv", "wo")},
-             "norm2": {"scale": lw["ffn_norm"]}}
-    ffn = {k: lw[k] for k in ("w1", "w2", "w3")}
-    if "router" in lw:
-        layer["moe"] = dict(ffn, router=lw["router"])
-    else:
-        layer["mlp"] = ffn
-    params = {"embed": weights["embed"], "blocks": {"l0": layer},
-              "final_norm": {"scale": weights["final_norm"]}}
-    want = jax.eval_shape(lambda: Mod.init_model(jax.random.PRNGKey(0), mcfg))
-    got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-    if jax.tree.structure(want) != jax.tree.structure(got) or any(
-            (a.shape, a.dtype) != (b.shape, b.dtype)
-            for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got))):
-        raise ValueError(f"weights do not match the program's tree:\n"
-                         f"want {want}\ngot {got}")
-    return params
 
 
 @dataclasses.dataclass
@@ -122,10 +54,9 @@ class Span:
 class Driver:
     def __init__(self, cfg: dict, weights: dict, seed: int):
         self.cfg = cfg
-        self.mcfg = model_config(cfg)
+        self.mcfg, params = arch.load(cfg).program(cfg, weights)
         sv = {k: cfg["serving"][k] for k in SERVING_KEYS if k in cfg["serving"]}
-        self.eng = ServingEngine(self.mcfg, to_params(weights, self.mcfg),
-                                 seed=seed, **sv)
+        self.eng = ServingEngine(self.mcfg, params, seed=seed, **sv)
         self.pending: collections.deque = collections.deque()
         self.spans: List[Span] = []
         # (rid, host time, tokens delivered at that time)

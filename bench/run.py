@@ -3,9 +3,11 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (an entry of `workloads` in BENCHMARK.json) names a configuration
-(`bench/configs/<config>.json`) and a traffic mix (`bench/traffic/<mix>.json`);
-its correctness limits are in `bench/checks/<cell>.json`, and each metric is
-read by `bench/metrics/<metric>.py`. Nothing here knows a cell by name.
+(`bench/configs/<config>.json`, whose `arch` names its architecture module
+`bench/arch/<arch>.py`) and a traffic mix (`bench/traffic/<mix>.json`); its
+correctness limits are in `bench/checks/<cell>.json`, and each metric is
+read by `bench/metrics/<metric>.py`. Nothing here knows a cell or a model's
+layers by name.
 
 Set-up (timed as setup_s, from process start to the window's opening):
 weights from the seed, the engine, every program shape the cell's traffic
@@ -227,6 +229,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
     `correct`: a sound control run is not correct."""
     import jax
     import numpy as np
+    import arch
     import check
     import driver
     import peaks
@@ -244,7 +247,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
     jax.block_until_ready(w)
     t_w = clock()
     drv = driver.Driver(cfg, w, W.jax_seed(seed))
-    for d in driver.registry_departures(cfg):
+    for d in arch.load(cfg).departures(cfg):
         print(f"note: program config departs from file: {d}", file=log)
     plan = traffic.make(mix, seed, vocab=cfg["vocab_size"], slots=drv.slots,
                         seconds=seconds)
@@ -307,15 +310,19 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
                         checks.get("sample_min_requests", 1))
     if not verify:
         rids = []
-    cmp = check.compare(w, cfg, prompts, results, rids, control=control)
+    cmp = check.compare(w, cfg, prompts, results, rids, control=control,
+                        router_margin=checks.get("router_margin"))
     ref_s = clock() - t_ref
     limit = checks["widest_logit_gap"]
+    min_tokens = checks.get("min_tokens_compared", 1)
     gap = cmp["control_widest_logit_gap" if control else "widest_logit_gap"]
     lines = {"widest_logit_gap": {"value": gap, "limit": limit},
              "failed_requests": {"value": failed, "limit": 0},
-             "requests_compared": {"value": cmp["requests_compared"], "limit": 1}}
+             "requests_compared": {"value": cmp["requests_compared"], "limit": 1},
+             "tokens_compared": {"value": cmp["tokens_compared"], "limit": min_tokens}}
     correct = (gap <= limit and failed == 0
-               and cmp["requests_compared"] >= 1)
+               and cmp["requests_compared"] >= 1
+               and cmp["tokens_compared"] >= min_tokens)
     import tails
     gaps = tails.itl_gaps(run.deliveries, run.t_open, run.t_end)
     tt = tails.ttfts(run.deliveries, run.due)
@@ -333,7 +340,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
           f"{late[len(late) // 2] if late else 0:.6f} s max "
           f"{late[-1] if late else 0:.6f} s", file=log)
     print(f"reference: {cmp['tokens_compared']} tokens of "
-          f"{cmp['requests_compared']} requests in {ref_s:.3f} s; logit "
+          f"{cmp['requests_compared']} requests in {ref_s:.3f} s "
+          f"({cmp['tokens_left_out']} left out at router near-ties); logit "
           f"spread {cmp['logit_spread']:.4f}", file=log)
     if control:
         print(f"control: the fp8 reference in the engine's place; engine's "

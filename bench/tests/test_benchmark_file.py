@@ -1,11 +1,13 @@
 """BENCHMARK.json is well formed and every name in it resolves to its
-file: configuration, traffic mix, checks and a reader per metric."""
+file: configuration and its architecture module, traffic mix, checks and a
+reader per metric."""
 import json
 import re
 from pathlib import Path
 
 import pytest
 
+import arch
 import run
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -25,6 +27,7 @@ def test_names_and_files():
         assert NAME.match(c["name"])
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+        assert len(arch.load(cfg).layers(cfg)) == cfg["num_hidden_layers"]
     for w in BENCH["workloads"]:
         assert NAME.match(w["name"]) and w["config"] in names
         assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").exists()

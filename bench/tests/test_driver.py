@@ -7,6 +7,7 @@ import pytest
 
 import driver
 import weights as W
+from arch.granite_swat import model_config, to_params
 from repro.serving.engine import Request, ServingEngine
 from tiny import dense, moe
 
@@ -24,8 +25,8 @@ def test_driver_matches_engine_run(make):
         drv.submit(rid, prompt, m)
     while len(drv.results) < len(reqs):
         drv.step(time.perf_counter)
-    mc = driver.model_config(cfg)
-    eng = ServingEngine(mc, driver.to_params(w, mc), seed=5,
+    mc = model_config(cfg)
+    eng = ServingEngine(mc, to_params(w, mc), seed=5,
                         **{k: cfg["serving"][k] for k in driver.SERVING_KEYS})
     want = {r.rid: (r.status, r.tokens)
             for r in eng.run([Request(i, p, max_new_tokens=m) for i, p, m in reqs])}

@@ -5,7 +5,9 @@ once for each fault a one-chip serving cell can have (a token altered
 where it is produced, a decode step that returns its cache unchanged, half
 of the batch left out), on smoke-size copies of the dense configuration the
 cells run and of the MoE one; and the control, put in the engine's place,
-comes out not correct: its widest gap lies above the cell's limit."""
+comes out not correct: its widest gap lies above the cell's limit. With a
+router margin the MoE's near-ties are left out of the comparison, faults
+still show, and a run that leaves out too much is not correct."""
 import json
 import os
 import subprocess
@@ -38,8 +40,9 @@ def _limit(mix):
     return json.loads(path.read_text())["widest_logit_gap"]
 
 
-def _execute(cfg, mix, seed, control=False, trace=False, every_request=False):
-    checks = dict(CHECKS, widest_logit_gap=_limit(mix))
+def _execute(cfg, mix, seed, control=False, trace=False, every_request=False,
+             **more_checks):
+    checks = dict(CHECKS, widest_logit_gap=_limit(mix), **more_checks)
     if every_request:
         checks.update(sample_tokens=10**9, sample_requests=10**9)
     specs = run.metric_specs(BENCH, CELL_OF[mix["loop"]], trace)
@@ -128,3 +131,42 @@ def test_control_is_not_correct(mix):
     assert not out["correct"]
     assert gap["value"] == c["control_widest_logit_gap"] > gap["limit"]
     assert c["widest_logit_gap"] < gap["limit"]
+
+
+# router logits of the smoke MoE are about unit-sized, and bf16 moves one by
+# about 0.01: within 0.05 of the k-th, the choice of experts is rounding's
+MARGIN = 0.05
+
+
+def test_router_near_ties_are_left_out():
+    out = _execute(moe(), CLOSED, 2**31 + 99, router_margin=MARGIN)
+    c = out["compared"]
+    assert out["correct"], out["checks"]
+    assert c["tokens_left_out"] > 0 and c["tokens_compared"] > 0
+    assert out["checks"]["tokens_compared"] == {"value": c["tokens_compared"],
+                                                "limit": 1}
+
+
+def test_too_few_tokens_compared_is_not_correct():
+    """Every position left out (a margin no router gap reaches), or fewer
+    tokens left than the checks ask for (4 requests of at most 24 tokens
+    leave at most 96): not correct."""
+    out = _execute(moe(), CLOSED, 2**31 + 99, router_margin=1e9)
+    assert out["compared"]["tokens_compared"] == 0 and not out["correct"]
+    out = _execute(moe(), CLOSED, 2**31 + 99, router_margin=MARGIN,
+                   min_tokens_compared=97)
+    tc = out["checks"]["tokens_compared"]
+    assert tc["value"] < tc["limit"] == 97 and not out["correct"]
+
+
+@pytest.mark.parametrize("fault", [_altered_token, _state_unchanged, _half_batch],
+                         ids=["token_altered", "state_unchanged", "half_batch"])
+@pytest.mark.parametrize("mix", [CLOSED, BUSY], ids=["closed", "open"])
+def test_fault_is_not_correct_at_router_margin(fresh_programs, monkeypatch,
+                                               fault, mix):
+    fault(monkeypatch)
+    out = _execute(moe(), mix, 5, every_request=True, router_margin=MARGIN)
+    assert not out["correct"]
+    assert out["compared"]["tokens_left_out"] > 0
+    gap = out["checks"]["widest_logit_gap"]
+    assert gap["value"] > gap["limit"]
