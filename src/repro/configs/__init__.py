@@ -36,6 +36,7 @@ _ALIASES = {
     "jamba-1.5-large-398b": "jamba_1p5_large",
     "granite-moe-1b-a400m": "granite_moe_1b",
     "moonshot-v1-16b-a3b": "moonshot_v1_16b",
+    "trinity-mini": "trinity_mini",
 }
 
 
@@ -71,8 +72,11 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
     ratio = max(1, cfg.num_heads // max(cfg.num_kv_heads, 1))
     heads = min(cfg.num_heads, 4)
     kv = max(1, heads // ratio)
-    moe = (MoESpec(num_experts=min(cfg.moe.num_experts, 4),
-                   top_k=min(cfg.moe.top_k, 2))
+    moe = (dataclasses.replace(
+               cfg.moe, num_experts=min(cfg.moe.num_experts, 4),
+               top_k=min(cfg.moe.top_k, 2), held=0,
+               expert_width=min(cfg.moe.expert_width, 128),
+               shared_width=min(cfg.moe.shared_width, 128))
            if cfg.moe.enabled else MoESpec())
     ssm = dataclasses.replace(cfg.ssm, state_dim=min(cfg.ssm.state_dim, 16),
                               head_dim=min(cfg.ssm.head_dim, 16),

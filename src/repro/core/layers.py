@@ -94,6 +94,9 @@ class AttentionLayerCfg:
     rope_theta: float = 10000.0
     use_rope: bool = True
     cross: bool = False          # cross-attention (whisper decoder)
+    qk_norm: bool = False        # RMSNorm over head_dim on q and k
+    gate: bool = False           # output times sigmoid(x @ wg)
+    norm_eps: float = 1e-6       # the qk norms' epsilon
 
 
 def init_attention(key, cfg: AttentionLayerCfg, dtype=jnp.bfloat16) -> Params:
@@ -109,10 +112,18 @@ def init_attention(key, cfg: AttentionLayerCfg, dtype=jnp.bfloat16) -> Params:
         p["bq"] = jnp.zeros((hq * dh,), dtype)
         p["bk"] = jnp.zeros((hkv * dh,), dtype)
         p["bv"] = jnp.zeros((hkv * dh,), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = init_rmsnorm(dh)
+        p["k_norm"] = init_rmsnorm(dh)
+    if cfg.gate:
+        p["wg"] = _dense_init(jax.random.fold_in(key, 4), (dm, hq * dh),
+                              dtype=dtype)
     return p
 
 
 def _project_qkv(params, cfg: AttentionLayerCfg, x, kv_x):
+    """q (B, Hq, L, D), k and v (B, Hkv, Lkv, D); with `qk_norm`, q and k
+    RMS-normed over D (scope `qk_norm`), before any rotary."""
     b, l, _ = x.shape
     lkv = kv_x.shape[1]
     q = x @ params["wq"]
@@ -120,16 +131,32 @@ def _project_qkv(params, cfg: AttentionLayerCfg, x, kv_x):
     v = kv_x @ params["wv"]
     if cfg.qkv_bias:
         q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
-    q = q.reshape(b, l, cfg.num_heads, cfg.head_dim).transpose(0, 2, 1, 3)
-    k = k.reshape(b, lkv, cfg.num_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
-    v = v.reshape(b, lkv, cfg.num_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
-    return q, k, v
+    q = q.reshape(b, l, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(b, lkv, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(b, lkv, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
+            k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
+    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3))
+
+
+def _output(params, cfg: AttentionLayerCfg, x, out):
+    """The output projection of the attention `out` (B, L, Hq * D), gated
+    elementwise by sigmoid(x @ wg) first where the layer has a gate
+    (scope `gate`)."""
+    if cfg.gate:
+        with jax.named_scope("gate"):
+            out = out * jax.nn.sigmoid(x @ params["wg"]).astype(out.dtype)
+    return out @ params["wo"]
 
 
 def attention_layer(params: Params, cfg: AttentionLayerCfg, x, *,
                     kv_x=None, positions=None, impl: str = "xla") -> jax.Array:
     """Full-sequence attention (training / prefill). x: (B, L, Dm).
-    Scopes `qkv`, `attend`, `out`."""
+    Scopes `qkv` (`qk_norm`), `attend` (`attend_full` on a full layer),
+    `out` (`gate`)."""
     b, l, _ = x.shape
     kv_x = x if kv_x is None else kv_x
     with jax.named_scope("qkv"):
@@ -138,11 +165,11 @@ def attention_layer(params: Params, cfg: AttentionLayerCfg, x, *,
             pos = (jnp.arange(l) if positions is None else positions)
             q = apply_rope(q, pos, cfg.rope_theta)
             k = apply_rope(k, pos, cfg.rope_theta)
-    with jax.named_scope("attend"):
+    with jax.named_scope(kops.attend_scope(cfg.spec)):
         out = kops.swat_attention(q, k, v, cfg.spec, impl=impl)
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, l, -1)
-        return out @ params["wo"]
+        return _output(params, cfg, x, out)
 
 
 # KV cache ------------------------------------------------------------------
@@ -339,8 +366,9 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
     mesh the caller's jit is partitioned over (see `decode_attention`).
 
     Scopes: `qkv`, `ring_write` (the ring insert; a paged cache's
-    write-back too), `attend` (a paged cache's gather too), `out`. The
-    pallas kernel inserts inside its attention pass, under `attend`."""
+    write-back too), `attend` (`attend_full` on a full layer; a paged
+    cache's gather too), `out`. The pallas kernel inserts inside its
+    attention pass, under the same scope."""
     b, t, _ = x.shape
     step = take_layer(cache["step"], layer).astype(jnp.int32)
     step = jnp.broadcast_to(step, (b,))
@@ -393,7 +421,7 @@ def attention_decode(params: Params, cfg: AttentionLayerCfg, x, cache, *,
                          v=put_layer(cache["v"], layer, v_cache))
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
-        return out @ params["wo"], new_cache
+        return _output(params, cfg, x, out), new_cache
 
 
 def _dyn_update(cache, new, slot):
@@ -495,7 +523,7 @@ def attention_prefill_chunk(params: Params, cfg: AttentionLayerCfg, x, cache,
     the padded batch in lockstep); per-row raggedness comes from `lengths`:
     rows stop writing past their own length and their surplus outputs are
     garbage the caller discards. Returns (output (B, T, Dm), new cache).
-    Scopes `qkv`, `attend`, `ring_write`, `out`."""
+    Scopes `qkv`, `attend` (`attend_full`), `ring_write`, `out`."""
     assert cfg.spec.causal and not cfg.cross
     b, t, _ = x.shape
     pos = pos0 + jnp.arange(t, dtype=jnp.int32)            # (T,) absolute
@@ -504,7 +532,7 @@ def attention_prefill_chunk(params: Params, cfg: AttentionLayerCfg, x, cache,
         if cfg.use_rope:
             q = apply_rope(q, pos, cfg.rope_theta)
             k_new = apply_rope(k_new, pos, cfg.rope_theta)
-    with jax.named_scope("attend"):
+    with jax.named_scope(kops.attend_scope(cfg.spec)):
         out = _chunk_attend(cfg, q, k_new, v_new, cache, pos, pos0, lengths,
                             lookahead, x.dtype)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -519,7 +547,7 @@ def attention_prefill_chunk(params: Params, cfg: AttentionLayerCfg, x, cache,
             "step": jnp.minimum(lens, pos0 + t).astype(jnp.int32),
         }
     with jax.named_scope("out"):
-        return out @ params["wo"], new_cache
+        return _output(params, cfg, x, out), new_cache
 
 
 def _chunk_attend(cfg: AttentionLayerCfg, q, k_new, v_new, cache, pos, pos0,

@@ -17,8 +17,18 @@ Three entry points per model: `loss_fn` (train), `prefill`, `decode_step`.
 Serving programs (`prefill`, `prefill_chunk`, `decode_step`) carry
 `jax.named_scope`s that a device trace reports with each operation:
 `embed`, then per layer `attn` (norm through the residual add; children
-`qkv`, `ring_write`, `attend`, `out`) and `mlp` or `moe` (norm through the
-residual add), then `unembed`. Scopes are metadata: they change no value.
+`qkv` with `qk_norm`, `ring_write`, `attend` or, on a full layer,
+`attend_full`, `out` with `gate`) and `mlp` or `moe` (norm through the
+residual add; `moe` has `router`, `experts`, `shared`), then `unembed`.
+Scopes are metadata: they change no value.
+
+Trinity's (afmoe) block: a layer pattern that lists every layer of one
+super-block (leading dense "attn" layers, then "attn_moe"), a window
+schedule of windows and None (full layers, without rotary where
+cfg.full_rope is off), QK norms and an output gate in the attention
+(`qk_norm`, `attn_gate`), and sandwich norms (`sandwich_norm`: each
+sub-block's output RMS-normed by post_norm1 / post_norm2 before its
+residual add).
 """
 from __future__ import annotations
 
@@ -49,7 +59,8 @@ def attn_cfg(cfg: ModelConfig, kind: str, cross: bool = False,
     names a window there, it overrides this layer's attention spec (sparse
     specs keep num_global/softcap; dense specs become causal swat windows).
     Cache capacities follow the overridden spec, so scheduled layers
-    allocate their own ring shapes."""
+    allocate their own ring shapes. Full (unwindowed) layers carry rotary
+    positions only where cfg.full_rope."""
     spec = cfg.local_attention if kind == "local_attn" else cfg.attention
     if cross:
         spec = AttentionSpec(kind="dense", causal=False)
@@ -65,7 +76,9 @@ def attn_cfg(cfg: ModelConfig, kind: str, cross: bool = False,
         d_model=cfg.d_model, num_heads=cfg.num_heads,
         num_kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
         spec=spec, qkv_bias=cfg.qkv_bias, rope_theta=cfg.rope_theta,
-        use_rope=cfg.use_rope, cross=cross)
+        use_rope=cfg.use_rope and (cfg.full_rope or spec.is_sparse),
+        cross=cross, qk_norm=cfg.qk_norm and not cross,
+        gate=cfg.attn_gate and not cross, norm_eps=cfg.norm_eps)
 
 
 # ------------------------------------------------------------------ init ---
@@ -90,7 +103,20 @@ def _init_layer(key, cfg: ModelConfig, kind: str,
     elif cfg.d_ff > 0:
         p["norm2"] = L.init_rmsnorm(cfg.d_model)
         p["mlp"] = L.init_mlp(ks[3], cfg.d_model, cfg.d_ff, dtype=dt)
+    if cfg.sandwich_norm:
+        p["post_norm1"] = L.init_rmsnorm(cfg.d_model)
+        if "norm2" in p:
+            p["post_norm2"] = L.init_rmsnorm(cfg.d_model)
     return p
+
+
+def _residual(p: Params, cfg: ModelConfig, norm: str, x, y):
+    """x + y, with y RMS-normed by the layer's `norm` gain first where the
+    layer has one (sandwich norm: post_norm1 after the mixer, post_norm2
+    after the FFN)."""
+    if norm in p:
+        y = L.rmsnorm(p[norm], y, cfg.norm_eps)
+    return x + y
 
 
 def _init_super_block(key, cfg: ModelConfig, pattern) -> Params:
@@ -139,11 +165,11 @@ def _apply_layer(p: Params, cfg: ModelConfig, kind: str, x, *,
                  index: Optional[int] = None):
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     if kind.startswith("mamba"):
-        x = x + S.mamba_block(p["mixer"], h, cfg.ssm,
-                              chunk=cfg.ssm.chunk_size)
+        y = S.mamba_block(p["mixer"], h, cfg.ssm, chunk=cfg.ssm.chunk_size)
     else:
-        x = x + L.attention_layer(p["mixer"], attn_cfg(cfg, kind, index=index),
-                                  h, positions=positions, impl=impl)
+        y = L.attention_layer(p["mixer"], attn_cfg(cfg, kind, index=index),
+                              h, positions=positions, impl=impl)
+    x = _residual(p, cfg, "post_norm1", x, y)
     if kind == "xattn":
         h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
         x = x + L.attention_layer(p["cross"], attn_cfg(cfg, kind, cross=True),
@@ -152,10 +178,10 @@ def _apply_layer(p: Params, cfg: ModelConfig, kind: str, x, *,
     if "moe" in p:
         h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
         y, aux = M.moe_ffn(p["moe"], h, cfg.moe)
-        x = x + y
+        x = _residual(p, cfg, "post_norm2", x, y)
     elif "mlp" in p:
         h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-        x = x + L.mlp(p["mlp"], h)
+        x = _residual(p, cfg, "post_norm2", x, L.mlp(p["mlp"], h))
     return x, aux
 
 
@@ -234,11 +260,11 @@ def _ffn_serving(p, cfg: ModelConfig, x):
         with jax.named_scope("moe"):
             h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
             y, _ = M.moe_ffn(p["moe"], h, cfg.moe, dispatch="dense")
-            return x + y
+            return _residual(p, cfg, "post_norm2", x, y)
     if "mlp" in p:
         with jax.named_scope("mlp"):
             h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-            return x + L.mlp(p["mlp"], h)
+            return _residual(p, cfg, "post_norm2", x, L.mlp(p["mlp"], h))
     return x
 
 
@@ -403,7 +429,7 @@ def _apply_layer_decode(p, cfg, kind, x, cache, *, layer, enc_out=None,
             y, new_cache = L.attention_decode(
                 p["mixer"], attn_cfg(cfg, kind, index=index), h, cache,
                 layer=layer, impl=impl, lookahead=lookahead, mesh=mesh)
-        x = x + y
+        x = _residual(p, cfg, "post_norm1", x, y)
     if kind == "xattn":
         with jax.named_scope("xattn"):
             h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
@@ -512,7 +538,7 @@ def prefill(params: Params, cfg: ModelConfig, batch, max_len: int, *,
                         cache = L.prefill_kv_cache(
                             p["mixer"], acfg, h, max_len, lengths=lengths,
                             lookahead=lookahead)
-                x = x + y
+                x = _residual(p, cfg, "post_norm1", x, y)
             if kind == "xattn":
                 with jax.named_scope("xattn"):
                     h = L.rmsnorm(p["norm_x"], x, cfg.norm_eps)
@@ -585,7 +611,7 @@ def prefill_chunk(params: Params, cfg: ModelConfig, batch, caches, pos0,
                 y, nc = L.attention_prefill_chunk(
                     p["mixer"], attn_cfg(cfg, kind, index=i), h,
                     blk_cache[f"l{i}"], pos0, lengths, lookahead=lookahead)
-                x = x + y
+                x = _residual(p, cfg, "post_norm1", x, y)
             x = _ffn_serving(p, cfg, x)
             new_caches[f"l{i}"] = nc
         return L.with_activation_constraint(x, act_sharding), new_caches

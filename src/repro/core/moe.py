@@ -18,7 +18,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.layers import _dense_init
+from repro.core.layers import _dense_init, init_mlp, mlp
 from repro.core.types import MoESpec
 
 Params = Dict[str, Any]
@@ -70,14 +70,54 @@ def _constrain(x, *spec):
 
 def init_moe(key, d_model: int, d_ff: int, spec: MoESpec,
              dtype=jnp.bfloat16) -> Params:
+    """The router scores all `num_experts`; w1/w3/w2 hold the held experts
+    only, each `expert_width` wide (d_ff where 0). Sigmoid routing adds its
+    selection bias, a shared expert its own gated MLP."""
     ks = jax.random.split(key, 4)
-    e = spec.num_experts
-    return {
-        "router": _dense_init(ks[0], (d_model, e), dtype=jnp.float32),
-        "w1": _dense_init(ks[1], (e, d_model, d_ff), dtype=dtype),
-        "w3": _dense_init(ks[2], (e, d_model, d_ff), dtype=dtype),
-        "w2": _dense_init(ks[3], (e, d_ff, d_model), dtype=dtype),
+    e, f = spec.held_count, spec.expert_width or d_ff
+    p = {
+        "router": _dense_init(ks[0], (d_model, spec.num_experts),
+                              dtype=jnp.float32),
+        "w1": _dense_init(ks[1], (e, d_model, f), dtype=dtype),
+        "w3": _dense_init(ks[2], (e, d_model, f), dtype=dtype),
+        "w2": _dense_init(ks[3], (e, f, d_model), dtype=dtype),
     }
+    if spec.score == "sigmoid":
+        p["bias"] = jnp.zeros((spec.num_experts,), jnp.float32)
+    if spec.shared_width:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d_model,
+                               spec.shared_width, dtype=dtype)
+    return p
+
+
+def route(params: Params, xf, spec: MoESpec):
+    """Routing of tokens xf (T, D) over all `num_experts`: (probs (T, E)
+    for the load-balance loss, gate_vals (T, k), expert_ids (T, k)).
+
+    softmax: the top-k of the softmax of the router logits, renormalised
+    to sum 1. sigmoid: scores s = sigmoid(logits); the experts chosen are
+    the top-k of s + bias (the bias steers selection only), weighted by s
+    renormalised over the k chosen. Either is then scaled by route_scale.
+    The sigmoid router runs at full float32 precision: its top-k sits on
+    score gaps of about 1e-2, which a one-pass bf16 matmul can flip."""
+    k = spec.top_k
+    if spec.score == "sigmoid":
+        logits = jnp.dot(xf.astype(jnp.float32), params["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.sigmoid(logits)
+        _, expert_ids = jax.lax.top_k(probs + params["bias"], k)
+        gate_vals = jnp.take_along_axis(probs, expert_ids, axis=-1)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
+                                 + 1e-20)
+    else:
+        logits = (xf.astype(jnp.float32) @ params["router"])    # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_ids = jax.lax.top_k(probs, k)         # (T, k)
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)        # renormalize
+    if spec.route_scale != 1.0:
+        gate_vals = gate_vals * spec.route_scale
+    return probs, gate_vals, expert_ids
 
 
 def moe_ffn(params: Params, x, spec: MoESpec, *,
@@ -90,22 +130,29 @@ def moe_ffn(params: Params, x, spec: MoESpec, *,
     decode) passes "dense": capacity-based dispatch couples tokens through
     the shared capacity sort, so a row's output would depend on its
     batch-mates and padding — the tokens-stationary combine is exact and
-    padding-invariant, which continuous batching requires."""
+    padding-invariant, which continuous batching requires. Only the dense
+    dispatch computes a held share of the experts (`spec.held`); the
+    others refuse one.
+
+    Scopes `router`, `experts` and `shared` (a shared expert's MLP, added
+    to the routed experts' output)."""
     b, l, d = x.shape
     e, k = spec.num_experts, spec.top_k
     t = b * l
     xf = x.reshape(t, d)
     dispatch = spec.dispatch if dispatch is None else dispatch
+    if dispatch != "dense" and not spec.whole:
+        raise ValueError(
+            f"a held share of the experts ({spec.held_count} of {e}) runs "
+            f"only under dense dispatch, not {dispatch!r}")
 
-    logits = (xf.astype(jnp.float32) @ params["router"])        # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, k)             # (T, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9)            # renormalize
+    with jax.named_scope("router"):
+        probs, gate_vals, expert_ids = route(params, xf, spec)
 
     if dispatch == "dense":
-        out = _dense_combine(params, xf, gate_vals, expert_ids, e)
-        out = out.reshape(b, l, d)
+        with jax.named_scope("experts"):
+            out = _dense_combine(params, xf, gate_vals, expert_ids, spec)
+        out = _add_shared(params, xf, out).reshape(b, l, d)
         if return_aux:
             flat_e = expert_ids.reshape(-1)
             frac = jnp.bincount(flat_e, length=e).astype(jnp.float32) / (t * k)
@@ -116,6 +163,9 @@ def moe_ffn(params: Params, x, spec: MoESpec, *,
     if dispatch == "ep":
         mesh = _ambient_mesh_with("model")
         if mesh is not None and e % mesh.shape["model"] == 0:
+            if spec.score != "softmax" or spec.shared_width:
+                raise ValueError("the 'ep' dispatch routes by softmax "
+                                 "and has no shared expert")
             from repro.core import moe_ep
             return moe_ep.moe_ffn_ep(params, x, spec, mesh=mesh,
                                      axis="model",
@@ -155,7 +205,7 @@ def moe_ffn(params: Params, x, spec: MoESpec, *,
     w = jnp.where(keep, gate_vals.reshape(-1)[order], 0.0)
     out = jnp.zeros((t, d), jnp.float32).at[src_token].add(
         gathered.astype(jnp.float32) * w[:, None])
-    out = out.astype(x.dtype).reshape(b, l, d)
+    out = _add_shared(params, xf, out.astype(x.dtype)).reshape(b, l, d)
 
     # Switch-style load-balance aux: E * sum_e f_e * P_e
     frac = jnp.bincount(flat_e, length=e).astype(jnp.float32) / (t * k)
@@ -164,15 +214,20 @@ def moe_ffn(params: Params, x, spec: MoESpec, *,
     return (out, aux) if return_aux else (out, jnp.zeros((), jnp.float32))
 
 
-def _dense_combine(params: Params, xf, gate_vals, expert_ids, e):
-    """Tokens-stationary dispatch: every expert runs on every (local) token,
-    outputs combined by the sparse gate matrix. No sort, no scatter, no
-    capacity, no all-to-all — the only collective left is the FSDP gather of
-    the (small) expert weights. The (E, T_local, F) intermediate stays
-    token-sharded under SPMD because xf's token dim is sharded."""
+def _dense_combine(params: Params, xf, gate_vals, expert_ids,
+                   spec: MoESpec):
+    """Tokens-stationary dispatch: every held expert runs on every (local)
+    token, outputs combined by the sparse gate matrix. No sort, no scatter,
+    no capacity, no all-to-all — the only collective left is the FSDP
+    gather of the (small) expert weights. The (E, T_local, F) intermediate
+    stays token-sharded under SPMD because xf's token dim is sharded. A
+    held share takes its experts' columns of the gate matrix: what the
+    other experts add is left to the devices that hold them."""
     t = xf.shape[0]
-    full_gates = jnp.zeros((t, e), jnp.float32).at[
+    full_gates = jnp.zeros((t, spec.num_experts), jnp.float32).at[
         jnp.arange(t)[:, None], expert_ids].set(gate_vals)      # (T, E)
+    if not spec.whole:
+        full_gates = full_gates[:, :spec.held_count]
     h = jax.nn.silu(jnp.einsum("td,edf->etf", xf, params["w1"]))
     h = h * jnp.einsum("td,edf->etf", xf, params["w3"])
     y = jnp.einsum("etf,efd->etd", h, params["w2"])             # (E, T, D)
@@ -180,9 +235,19 @@ def _dense_combine(params: Params, xf, gate_vals, expert_ids, e):
     return out.astype(xf.dtype)
 
 
+def _add_shared(params: Params, xf, out):
+    """The routed experts' output plus the shared expert's, if any."""
+    if "shared" not in params:
+        return out
+    with jax.named_scope("shared"):
+        return out + mlp(params["shared"], xf).astype(out.dtype)
+
+
 def moe_ffn_dense_ref(params: Params, x, spec: MoESpec):
     """O(E) reference: compute every expert for every token, combine by the
-    (renormalized) top-k gates. Oracle for tests (no capacity drops)."""
+    (renormalized) top-k gates. Oracle for tests (no capacity drops) of
+    softmax routing over every expert."""
+    assert spec.score == "softmax" and spec.whole and not spec.shared_width
     b, l, d = x.shape
     xf = x.reshape(-1, d)
     logits = xf.astype(jnp.float32) @ params["router"]

@@ -72,17 +72,46 @@ class MoESpec:
               all-to-alls over 'model' (core/moe_ep.py). Wire bytes scale
               with LOCAL tokens only; the schedule production MoE systems
               use. Right when E/k is large (moonshot 64/6, jamba 16/2).
+
+    num_experts is the router's width: every token is routed over all of
+    them. held: this device's share under expert parallelism, experts
+    [0, held) (0: all of them); the layer computes only what its held
+    experts add, which only the dense dispatch does. expert_width: each
+    expert's FFN width (0: the model's d_ff).
+    shared_width: a shared expert every token passes through (0: none).
+    score: "softmax" (top-k of the softmax, renormalised) or "sigmoid"
+    (top-k of sigmoid scores plus a per-expert selection bias, the chosen
+    scores renormalised over the k chosen). route_scale multiplies the
+    renormalised weights.
     """
     num_experts: int = 0
     top_k: int = 0
     dispatch: str = "sort"
+    held: int = 0
+    expert_width: int = 0
+    shared_width: int = 0
+    score: str = "softmax"
+    route_scale: float = 1.0
 
     def __post_init__(self):
         assert self.dispatch in ("sort", "dense", "ep"), self.dispatch
+        assert self.score in ("softmax", "sigmoid"), self.score
+        assert self.held_count <= self.num_experts, (
+            f"{self.held_count} held experts outside the router's "
+            f"{self.num_experts}")
 
     @property
     def enabled(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_count(self) -> int:
+        return self.held or self.num_experts
+
+    @property
+    def whole(self) -> bool:
+        """Whether this device holds every expert the router scores."""
+        return self.held_count == self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,6 +174,17 @@ class ModelConfig:
     encoder_layers: int = 0
     max_decode_len: int = 0                # structural decoder limit (whisper: 448)
     dtype: str = "bfloat16"
+    # afmoe attention and residual paths (Trinity), each off by default:
+    # qk_norm  - RMSNorm over head_dim on queries and keys (own gains)
+    # attn_gate - attention output times sigmoid(h @ wg), elementwise
+    # sandwich_norm - each sub-block's output RMS-normed before its
+    #                 residual add (post_norm1 / post_norm2 gains)
+    # full_rope - rotary on full (non-windowed) attention layers; False
+    #             leaves them without positions (NoPE)
+    qk_norm: bool = False
+    attn_gate: bool = False
+    sandwich_norm: bool = False
+    full_rope: bool = True
 
     def __post_init__(self):
         assert self.num_layers % len(self.layer_pattern) == 0, (

@@ -37,13 +37,18 @@ _PARAM_RULES: List[Tuple[str, List[Tuple[int, Sequence[Any]]]]] = [
     (r"moe/w2$",              [(0, ("model",)), (1, ("data",))]),
     (r"embed$",               [(0, ("model", "data")), (1, ("data",))]),
     (r"lm_head$",             [(1, ("model", "data")), (0, ("data",))]),
-    (r"(wq|wk|wv|w1|w3)$",    [(1, ("model",)), (0, ("data",))]),
+    # the attention's output gate wg (d, hq*dh) is column-parallel like wq;
+    # a shared expert (moe/shared/w1|w3|w2) is a dense MLP, so it takes the
+    # generic rules, not the expert-parallel ones above
+    (r"(wq|wk|wv|wg|w1|w3)$", [(1, ("model",)), (0, ("data",))]),
     (r"(wo|w2|out_proj)$",    [(0, ("model",)), (1, ("data",))]),
     (r"in_proj$",             [(1, ("model",)), (0, ("data",))]),
     (r"router$",              [(0, ("data",))]),
     (r"conv_w$",              [(1, ("model",))]),
     (r"(bq|bk|bv|conv_b)$",   [(0, ("model",))]),
-    # norms, a_log, d_skip, dt_bias, scalars: replicated (no rule)
+    # norms (q_norm/k_norm over head_dim, post_norm1/post_norm2), the
+    # router's selection bias moe/bias, a_log, d_skip, dt_bias, scalars:
+    # replicated (no rule)
 ]
 
 _CACHE_RULES: List[Tuple[str, List[Tuple[int, Sequence[Any]]]]] = [
@@ -80,7 +85,7 @@ _PARAM_RULES_CP: List[Tuple[str, List[Tuple[int, Sequence[Any]]]]] = [
                                (1, ("data",))]),
     (r"lm_head$",             [(1, (("data", "model"), "data", "model")),
                                (0, ("data",))]),
-    (r"(wq|wk|wv|w1|w3|wo|w2|out_proj|in_proj)$",
+    (r"(wq|wk|wv|wg|w1|w3|wo|w2|out_proj|in_proj)$",
                               [(0, (("data", "model"), "data")),
                                (1, (("data", "model"), "data"))]),
     (r"router$",              [(0, ("data",))]),

@@ -246,6 +246,12 @@ def _cp_eligible(spec: AttentionSpec, lq: int, lkv: int) -> bool:
 # Public entry point
 # --------------------------------------------------------------------------
 
+def attend_scope(spec: AttentionSpec) -> str:
+    """Named scope of an attention pass: `attend` over a window,
+    `attend_full` over every earlier key, so a trace tells them apart."""
+    return "attend" if spec.is_sparse else "attend_full"
+
+
 def swat_attention(q, k, v, spec: AttentionSpec, *,
                    block_q: int = 128, block_kv: int = 128,
                    scale: Optional[float] = None,
@@ -353,6 +359,21 @@ def ring_rows_in_place(impl: str, paged: bool) -> bool:
     return impl != "pallas" and not paged
 
 
+def decode_rows_read(impl: str, cache_rows: int) -> int:
+    """Rows of one slot's cache that one layer's `decode_attention` reads
+    in a step, for a cache of `cache_rows` rows: every row, whatever the
+    slot's position. The ref impl scores every row and masks those past
+    the position; the pallas kernel's grid visits every `block_kv` block,
+    over a cache padded to whole blocks (`decode_block_kv`). A paged pool
+    is gathered into a whole contiguous cache first. A path that reads
+    only the rows a position needs changes this rule with it."""
+    if impl == "pallas":
+        from repro.kernels.swat_decode import decode_block_kv
+        block, _ = decode_block_kv(cache_rows)
+        return -(-cache_rows // block) * block
+    return cache_rows
+
+
 def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
                      scale: Optional[float] = None, impl: str = "ref",
                      interpret: Optional[bool] = None,
@@ -395,8 +416,9 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
     over; the pallas kernel then runs per shard (`_shard_decode_kernel`).
 
     Named scopes: the ref impl's fused insert is `ring_write` and its
-    attention `attend`; the pallas kernel, which inserts inside its
-    attention pass, is `attend`."""
+    attention `attend` (`attend_full` without a window, `attend_scope`);
+    the pallas kernel, which inserts inside its attention pass, takes
+    the same scope."""
     b, _, t, _ = q.shape
     w_phys = k_cache.shape[-2]
     cap = w_phys if ring_cap is None else int(ring_cap)
@@ -449,7 +471,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
             call, args = kern, (q, k_cache, v_cache, pos)
         if mesh is not None and mesh.size > 1:
             call = _shard_decode_kernel(call, mesh, k_cache.shape, fuse)
-        with jax.named_scope("attend"):
+        with jax.named_scope(attend_scope(spec)):
             return call(*args)
     if fuse:
         k_new, v_new = new_kv
@@ -467,7 +489,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, spec: AttentionSpec, *,
         kc, vc = k_cache, v_cache
         if layer is not None:
             kc, vc = ref_impl.take_layer(kc, lyr), ref_impl.take_layer(vc, lyr)
-        with jax.named_scope("attend"):
+        with jax.named_scope(attend_scope(spec)):
             out = ref_impl.decode_ref(q, kc, vc, None, spec, scale=scale,
                                       total=pos + nn, q0=pos, ring_cap=cap)
         return out, k_cache, v_cache

@@ -56,6 +56,7 @@ import numpy as np
 # sampled (temps>0) token streams but never greedy decoding.
 jax.config.update("jax_threefry_partitionable", True)
 
+from repro.core import layers as L
 from repro.core import model as Mod
 from repro.core.types import ModelConfig
 from repro.kernels import ops as kops
@@ -234,6 +235,23 @@ class _Compiled:
         return (any(not kind.startswith("mamba")
                     for kind in self.cfg.layer_pattern)
                 and kops.ring_rows_in_place(self.decode_impl, self.paged))
+
+    @functools.cached_property
+    def full_caches(self) -> Tuple[int, int]:
+        """(full-attention layers, rows of each one's cache a decode step
+        reads per slot, `kops.decode_rows_read`): the layers whose decode
+        attends a whole max_len cache, not a ring of a window's rows."""
+        layers, rows = 0, 0
+        for i, kind in enumerate(self.cfg.layer_pattern):
+            if kind.startswith("mamba"):
+                continue
+            acfg = Mod.attn_cfg(self.cfg, kind, index=i)
+            if not acfg.spec.is_sparse:
+                layers += self.cfg.num_super_blocks
+                rows = kops.decode_rows_read(
+                    self.decode_impl,
+                    L.cache_allocation(acfg, self.max_len, self.lookahead))
+        return layers, rows
 
     def batch_sharding(self, shapes, n: int, slot_dim: int = 0):
         """decode_batch_sharding over a pytree of ShapeDtypeStructs."""
@@ -1574,7 +1592,11 @@ class ServingEngine:
         steps instead, each emitting 1..speculative+1 tokens per slot.
 
         Host spans: `swat.decode` (n, slots, live, row_write: 1 if the
-        program writes K/V rows in place) around the block, with
+        program writes K/V rows in place; full_rows_needed: over the
+        block's steps, live slots and full-attention layers, the cache
+        rows a query may see, position + 1; full_rows_read: the rows
+        those layers' attention reads for the live slots over the block's
+        steps, by `kops.decode_rows_read`) around the block, with
         children `stage` (host work before dispatch: restaging the slot
         vectors and placing them), `dispatch` (the scan call until it
         returns), `sync` (the wait for its tokens) and `retire`
@@ -1589,8 +1611,17 @@ class ServingEngine:
         if not live:
             return []
         span = self.tracer.span
+        full, rows = self._c.full_caches
+        needed = 0
+        if full:
+            for s in live:
+                p = len(self.slot_req[s].prompt) + len(self.slot_out[s]) - 1
+                k = min(n, int(self.slot_budget[s]))
+                needed += k * (p + 1) + k * (k - 1) // 2
         with span("swat.decode", n=n, slots=self.slots, live=len(live),
-                  row_write=int(self._c.row_write)) as blk:
+                  row_write=int(self._c.row_write),
+                  full_rows_needed=full * needed,
+                  full_rows_read=full * n * len(live) * rows) as blk:
             with span("swat.decode.stage"):
                 use_spec, probe, inject = self._stage_block(n, live)
             dev = self._dev
